@@ -21,7 +21,7 @@ from .audit import AuditError, verify_output_dir
 from .config import IndexConfig
 from .corpus.manifest import read_manifest
 from .models.inverted_index import build_index
-from .utils import envknobs
+from .utils import device, envknobs
 from .utils.checkpoint import CheckpointCorrupt
 
 _EPILOG = """\
@@ -1335,6 +1335,7 @@ def main(argv: list[str] | None = None) -> int:
     # 'mri-tpu --verify out/' and 'mri-tpu query out/ word' work
     # without dummy mapper counts.
     argv = list(sys.argv[1:] if argv is None else argv)
+    device.setup()
     if argv and argv[0] == "query":
         return _query_main(argv[1:])
     if argv and argv[0] == "serve":

@@ -128,7 +128,7 @@ class IndexConfig:
     # (main.c:332-341), which survive a crash and make the remaining
     # work re-runnable.  Motivated by a real failure: the round-3
     # 1M-doc on-chip run lost ~9 minutes of stream to a TPU worker
-    # crash (SCALE_r03.json device_stream_real_tpu).
+    # crash (round 3).
     stream_checkpoint: str | None = None
     stream_checkpoint_every: int = 2
     # Checkpoint-trust policy at resume time:

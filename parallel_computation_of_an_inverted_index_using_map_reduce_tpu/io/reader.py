@@ -36,10 +36,7 @@ def read_doc_into(manifest, index: int, dest: memoryview) -> int:
     count, one that grew is truncated to the recorded size (manifest
     sizes are authoritative for window planning).
     """
-    inj = faults.active()
-    cap = None
-    if inj is not None:
-        cap = inj.on_read(index, manifest.paths[index])
+    cap = _fault_cap(manifest, index)
     fast = getattr(manifest, "read_doc_into", None)
     if fast is not None:
         n = fast(index, dest)
@@ -49,6 +46,30 @@ def read_doc_into(manifest, index: int, dest: memoryview) -> int:
         dest[:n] = data[:n]
     if cap is not None:
         n = min(n, cap)
+    return n
+
+
+def _fault_cap(manifest, index: int) -> int | None:
+    """The fault hook's read of document ``index``: it may raise, or
+    cap the bytes returned."""
+    inj = faults.active()
+    if inj is None:
+        return None
+    return inj.on_read(index, manifest.paths[index])
+
+
+def _read_doc_to_arena(manifest, index: int, arena: WindowArena) -> int:
+    """Document ``index`` into the arena's next (uncommitted) view;
+    bytes written.  A virtual corpus (no ``read_doc_into``) generates
+    the document once and sizes the view from it: its ``sizes`` are
+    planning estimates, not the length ``read_doc`` yields."""
+    if getattr(manifest, "read_doc_into", None) is not None:
+        return read_doc_into(manifest, index,
+                             arena.view(int(manifest.sizes[index])))
+    cap = _fault_cap(manifest, index)
+    data = manifest.read_doc(index)
+    n = len(data) if cap is None else min(len(data), cap)
+    arena.view(n)[:] = data[:n]
     return n
 
 
@@ -95,11 +116,9 @@ def read_window_into(manifest, lo: int, hi: int, arena: WindowArena,
     arena.reset()
     window_skips = 0
     for i in range(lo, hi):
-        size = int(manifest.sizes[i])
-        dest = arena.view(size)
         try:
             n = policy.run(
-                lambda: read_doc_into(manifest, i, dest),
+                lambda: _read_doc_to_arena(manifest, i, arena),
                 doc_id=manifest.doc_id(i), path=manifest.paths[i],
                 report=report)
         except OSError as e:

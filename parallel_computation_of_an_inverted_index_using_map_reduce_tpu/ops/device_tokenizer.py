@@ -75,7 +75,7 @@ _ONE_KEY_COMPACTION_LIMIT = 1 << 24
 # The round-2 MRI_TPU_LETTER_COMPACTION=searchsorted variant was
 # removed after the round-3 on-chip A/B: the cumsum-rank binary-search
 # compaction measured 2150.8 ms device_index vs 1156.6 ms for the
-# position-keyed sort on the v5e (BENCH_TPU_r03.json
+# position-keyed sort on the v5e (round-3 record,
 # letter_compaction_ab), and the sort formulation then absorbed the
 # letter payload for free.
 

@@ -65,21 +65,32 @@ def _quiet_donation(fn):
 #   "off"   — XLA everywhere
 _PALLAS_MODE = envknobs.get("MRI_TPU_PALLAS")
 
+#: which dedup the last trace took: "pallas" (compiled kernel),
+#: "pallas-interpret" or "xla".  ``InvertedIndexModel.run`` clears it
+#: first and reports it as ``dedup_kernel`` only if its own run traced a
+#: dedup (a jit-cache hit traces nothing), so a run can show the kernel
+#: really ran on the chip and never names an earlier run's choice.
+dedup_kernel: str | None = None
+
 
 def _dedup_mask(keys_s, valid_limit: int):
     """(mask, count) over ascending keys: first-occurrence & validity.
 
     Via the fused Pallas kernel when eligible (trace-time choice),
-    which also yields the unique count for free; the XLA fallback
-    returns ``count=None`` and callers reduce the mask instead.
+    which also yields the unique count for free; the XLA path returns
+    ``count=None`` and callers reduce the mask instead.
     """
+    global dedup_kernel
     if _PALLAS_MODE != "off":
         from .pallas import kernels as pk
 
         if pk.supports(keys_s.shape[0]) and (
             _PALLAS_MODE == "force" or jax.default_backend() == "tpu"
         ):
+            dedup_kernel = ("pallas-interpret" if pk._should_interpret()
+                            else "pallas")
             return pk.unique_mask_count(keys_s, valid_limit)
+    dedup_kernel = "xla"
     return first_occurrence_mask(keys_s) & (keys_s < valid_limit), None
 
 

@@ -69,7 +69,6 @@ from ..utils.rounding import round_up
 from .dist_device_tokenizer import _local_mesh_positions, _mix32, fetch_owner_blocks
 from .dist_engine import default_capacity
 from .mesh import SHARD_AXIS, replicated_spec, shard_spec, sharding
-from .compat import shard_map
 
 
 def _window_merge_body(acc_and_window, *, width: int, tok_cap: int,
@@ -160,7 +159,7 @@ def _build_merge(mesh: Mesh, width: int, tok_cap: int, num_docs: int,
 
     # no donation: an overflowing merge retries against the same
     # accumulator and window at a larger capacity
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         wrapper, mesh=mesh,
         in_specs=(shard_spec(),) * (nrows_acc + 3),
         out_specs={"acc": (shard_spec(),) * nrows_acc,
@@ -177,7 +176,7 @@ def _build_regrow(mesh: Mesh, old_cap: int, new_cap: int, nrows: int):
             return lax.dynamic_update_slice(out, a, (0,))
         return tuple(one(a) for a in acc)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(shard_spec(),) * nrows,
         out_specs=(shard_spec(),) * nrows, check_vma=False))
 
@@ -199,7 +198,7 @@ def _build_finalize(mesh: Mesh, cap: int, num_groups: int):
             "unique_groups": out["unique_groups"],
         }
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(shard_spec(),) * (2 * num_groups + 1),
         out_specs={"counts": shard_spec(), "maxima": replicated_spec(),
                    "df": shard_spec(), "postings": shard_spec(),

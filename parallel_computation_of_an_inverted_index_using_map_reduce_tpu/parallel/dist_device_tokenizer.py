@@ -63,7 +63,6 @@ from ..ops.segment import bucket_edges
 from ..utils.rounding import round_up as _round_up
 from .dist_engine import default_capacity
 from .mesh import SHARD_AXIS, replicated_spec, shard_spec, sharding
-from .compat import shard_map
 
 
 def _mix32(cols):
@@ -173,7 +172,7 @@ def _build(mesh: Mesh, width: int, tok_cap: int, num_docs: int,
         _body, width=width, tok_cap=tok_cap, num_docs=num_docs,
         num_shards=n, capacity=capacity, sort_cols=sort_cols,
         owner_of_letter=owner_of_letter)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(shard_spec(),) * 3,
         out_specs={"counts": shard_spec(), "globals": replicated_spec(),
@@ -212,7 +211,7 @@ def _build_prefix_slice(mesh: Mesh, nu: int, npairs: int, live: int,
         return tuple(out)
 
     nout = 4 + ((1 + 2 * (live - 1)) if nlong else 0)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(shard_spec(),) * (2 + 2 * live),
         out_specs=(shard_spec(),) * nout,
@@ -297,6 +296,8 @@ def index_bytes_dist(shard_bufs, shard_ends, shard_ids, *, width: int,
         sort_cols=sort_cols, max_doc_id=max_doc_id, max_words=int(g[3]),
         max_pairs=int(g[4]), max_long=int(g[5]), stats=stats)
     if stats is not None:
+        # the devices the program's output really lives on
+        stats["device_spread"] = len(out["counts"].sharding.device_set)
         stats["exchange_retries"] = retries
         stats["exchange_capacity"] = capacity
     return owners, (max_len, retries)

@@ -46,7 +46,6 @@ from ..ops.segment import (
 )
 from ..utils.rounding import round_up as _round_up
 from .mesh import SHARD_AXIS, make_mesh, replicated_spec, shard_spec, sharding
-from .compat import shard_map
 
 
 def default_capacity(local_size: int, num_shards: int, factor: float = 2.0) -> int:
@@ -138,7 +137,7 @@ def _build(mesh: Mesh, num_shards: int, capacity: int, vocab_size: int,
         "overflow": replicated_spec(),
     }
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh,
             in_specs=(shard_spec(), replicated_spec()),
             out_specs=out_specs,
@@ -238,7 +237,7 @@ def _build_prov(mesh: Mesh, num_windows: int, window_local: tuple,
         donate_argnums = tuple(range(num_windows))
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh,
             in_specs=in_specs,
             out_specs={"owned_sorted": shard_spec(),
@@ -306,6 +305,9 @@ def _exchange_and_fetch_rows(windows, *, stride: int, mesh: Mesh,
         rows[owner] = row[: counts[owner]]
         fetched += row.nbytes
     if stats is not None:
+        # the devices the exchange's output really lives on (not the
+        # mesh size asked for): a run can show it spread over the mesh
+        stats["device_spread"] = len(out["owned_sorted"].sharding.device_set)
         stats["dist_fetched_bytes"] = fetched + 4 * len(counts)
         stats["dist_valid_pairs"] = int(sum(counts.values()))
     return rows
@@ -336,7 +338,7 @@ def _build_prefix_slice(mesh: Mesh, local_len: int, nfetch: int):
     """Per-shard valid-prefix slice, compiled once per (len, nfetch)
     bucket: the owner sort packs real keys first, so ``x[:nfetch]`` on
     each shard drops the INT32_MAX padding *before* the D2H transfer."""
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         lambda x: x[:nfetch], mesh=mesh,
         in_specs=shard_spec(), out_specs=shard_spec(), check_vma=False))
 

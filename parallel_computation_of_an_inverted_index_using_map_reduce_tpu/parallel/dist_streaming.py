@@ -54,7 +54,6 @@ from ..ops.segment import bucket_edges, compact, first_occurrence_mask
 from ..utils.rounding import round_up
 from .dist_engine import _bucket_exchange, _build_prefix_slice, default_capacity
 from .mesh import SHARD_AXIS, replicated_spec, shard_spec, sharding
-from .compat import shard_map
 
 
 def _pair_bucket_exchange(term, doc, *, num_shards: int, capacity: int):
@@ -125,7 +124,7 @@ def _build_merge(mesh: Mesh, window_local: int, num_shards: int, cap: int,
 
     # no donation: an overflowing merge is retried against the same
     # accumulator and window at a larger capacity
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(shard_spec(), shard_spec()),
         out_specs={"acc": shard_spec(),
@@ -143,7 +142,7 @@ def _build_merge_pairs(mesh: Mesh, window_local: int, num_shards: int,
             acc_t, acc_d, win_t, win_d, num_shards=num_shards, cap=cap,
             exchange_capacity=exchange_capacity)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(shard_spec(),) * 4,
         out_specs={"acc_t": shard_spec(), "acc_d": shard_spec(),
@@ -159,7 +158,7 @@ def _build_regrow(mesh: Mesh, old_cap: int, new_cap: int):
         out = jnp.full((new_cap,), K.INT32_MAX, jnp.int32)
         return lax.dynamic_update_slice(out, acc_local, (0,))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=shard_spec(), out_specs=shard_spec(),
         check_vma=False))
 
@@ -173,7 +172,7 @@ def _build_unpack(mesh: Mesh, cap: int, stride: int):
         doc = jnp.where(valid, acc_local % stride, K.INT32_MAX)
         return term, doc
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=shard_spec(),
         out_specs=(shard_spec(), shard_spec()), check_vma=False))
 

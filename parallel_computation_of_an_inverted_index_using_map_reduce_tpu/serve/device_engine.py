@@ -3,9 +3,9 @@
 The host engine (:mod:`.engine`) answers batches with numpy over mmap
 views; this engine uploads the artifact's columns to device memory ONCE
 and answers large batches as jitted XLA programs, the batch dimension
-sharded across devices with ``shard_map`` through the
-``parallel/compat.py`` shim (DrJAX's broadcast/map/reduce shape, arxiv
-2403.07128: columns replicated, queries mapped, results concatenated).
+sharded across devices with ``jax.shard_map`` (DrJAX's
+broadcast/map/reduce shape, arxiv 2403.07128: columns replicated,
+queries mapped, results concatenated).
 
 Per batch the pipeline is
 
@@ -47,7 +47,10 @@ import numpy as np
 from . import artifact as artifact_mod
 from . import planner as planner_mod
 from .cache import LRUCache
-from .engine import BM25_B, BM25_K1, OpTimer, encode_terms, letter_index
+from .engine import (
+    BM25_B, BM25_K1, OpTimer, bm25_contrib, bm25_idf, encode_terms,
+    letter_index,
+)
 
 import jax
 import jax.numpy as jnp
@@ -55,9 +58,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..obs import attribution as obs_attrib
 from ..obs import metrics as obs_metrics
-from ..parallel.compat import shard_map
 from ..parallel.mesh import SHARD_AXIS, make_mesh
-from ..utils import envknobs
+from ..utils import device, envknobs
 
 #: pad value in posting windows: larger than any doc id (guarded at
 #: load), so sentinel lanes sort after every real doc.
@@ -71,6 +73,10 @@ DECODE_BUDGET_ENV = "MRI_SERVE_DEVICE_DECODE_BUDGET"  # default: envknobs
 
 #: smallest per-shard batch bucket: tiny batches all share one compile.
 _MIN_LANES = 8
+
+#: bound on the relative error of a float32 device BM25 score against
+#: its float64 value (a few ulps per term; generous on purpose)
+_F32_REL = 1e-4
 
 
 def _next_pow2(n: int) -> int:
@@ -117,7 +123,7 @@ def _make_lookup(mesh, nsteps: int, group: int):
         dfv = jnp.where(found, df[at], 0)
         return at.astype(jnp.int32), found, dfv
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P(), P(),
                   P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
@@ -142,7 +148,7 @@ def _make_decode(mesh, width: int):
     def body(post_offsets, postings, idx, n):
         return _decode_window(post_offsets, postings, idx, n, width=width)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P(SHARD_AXIS), P(SHARD_AXIS)),
         out_specs=P(SHARD_AXIS), check_vma=False))
@@ -221,7 +227,7 @@ def _make_decode_v2(mesh, width: int, block_size: int):
             term_block_off, blk_first, blk_width, blk_woff, post_words,
             idx, n, width=width, block_size=block_size)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(),
                   P(SHARD_AXIS), P(SHARD_AXIS)),
@@ -419,6 +425,10 @@ class DeviceEngine:
         self._d_rows = put(cols["rows"])
         self._d_df = put(cols["df"])
         self._d_df_order = put(cols["df_order"])
+        # where the columns really live (stats: a run can show the
+        # engine spread over its mesh)
+        self._placed_on = sorted(str(d)
+                                 for d in self._d_df.sharding.device_set)
         self._fmt = cols["format"]
         if self._fmt >= artifact_mod.VERSION_V2:
             self._block_size = cols["block_size"]
@@ -723,24 +733,52 @@ class DeviceEngine:
             self._bm25_host = artifact_mod.bm25_corpus(self.artifact)
         return self._bm25_host
 
-    def _term_contribs(self, i: int) -> np.ndarray:
-        """Term ``i``'s BM25 contributions, descending (f64, host)."""
+    def _term_contribs(self, i: int) -> tuple:
+        """``(docs, contrib, contrib_sorted_desc)`` for term ``i`` (f64,
+        host) — the host engine's shared ``bm25_contrib``, so the exact
+        rescoring below is bit-equal to ``Engine._term_scores``."""
         hit = self._score_memo.get(i)
         if hit is not None:
             return hit
         doc_lens, ndocs, avgdl = self._bm25_host_cols()
         art = self.artifact
-        docs = art.decode_postings(i)
+        docs = art.decode_postings(i).astype(np.int64)
         tf = art.decode_tf(i).astype(np.float64)
-        dfi = len(docs)
-        idf = np.log(1.0 + (ndocs - dfi + 0.5) / (dfi + 0.5))
-        denom = tf + BM25_K1 * (1.0 - BM25_B
-                                + BM25_B * doc_lens[docs] / avgdl)
-        srt = np.sort(idf * tf * (BM25_K1 + 1.0) / denom)[::-1]
+        contrib = bm25_contrib(bm25_idf(len(docs), ndocs), tf,
+                               doc_lens[docs], avgdl)
         if len(self._score_memo) >= self._memo_cap:
             self._score_memo.clear()
-        self._score_memo[i] = srt
-        return srt
+        self._score_memo[i] = (docs, contrib, np.sort(contrib)[::-1])
+        return self._score_memo[i]
+
+    def _exact_top_k(self, occ: list[int], k: int, D: int, run
+                     ) -> list[tuple[int, float]]:
+        """Exact BM25 top-k from the device's float32 candidates.
+
+        ``run(kk)`` is the device's top-``kk`` ``(ids, vals)``.  The
+        candidates are rescored in float64 on the host, summed in
+        occurrence order like the host engine, so scores and ties are
+        byte-equal to it.  The candidate set is complete once the exact
+        k-th score clears the device's smallest returned score by more
+        than float32 error (``_F32_REL``); until then ``kk`` grows."""
+        kk = min(D, _next_pow2(k + 16))
+        while True:
+            ids, vals = (np.asarray(a) for a in run(kk))
+            cand = ids[vals > 0.0].astype(np.int64)
+            exact = np.zeros(len(cand), np.float64)
+            for i in occ:
+                docs, contrib, _ = self._term_contribs(i)
+                pos = np.minimum(np.searchsorted(docs, cand),
+                                 max(len(docs) - 1, 0))
+                hit = docs[pos] == cand
+                exact[hit] += contrib[pos[hit]]
+            top = np.lexsort((cand, -exact))[:k]
+            complete = (len(cand) < kk or kk >= D or (
+                len(top) == k
+                and exact[top[-1]] > float(vals[-1]) * (1.0 + _F32_REL)))
+            if complete:
+                return [(int(cand[j]), float(exact[j])) for j in top]
+            kk = min(D, kk * 4)
 
     def _term_bounds(self, i: int) -> tuple:
         """(per-block f64 upper bounds, their max, idf) for term i."""
@@ -748,8 +786,7 @@ class DeviceEngine:
         if hit is not None:
             return hit
         doc_lens, ndocs, avgdl = self._bm25_host_cols()
-        dfi = int(self._h_df[i])
-        idf = np.log(1.0 + (ndocs - dfi + 0.5) / (dfi + 0.5))
+        idf = bm25_idf(int(self._h_df[i]), ndocs)
         ubs = planner_mod.block_upper_bounds(
             self.artifact, i, idf, avgdl, BM25_K1, BM25_B)
         if len(self._bound_memo) >= self._memo_cap:
@@ -780,7 +817,7 @@ class DeviceEngine:
         total = sum(w * umax for _i, w, _ubs, umax, _idf in terms)
         theta = 0.0
         for i, w, _ubs, _umax, _idf in terms:
-            srt = self._term_contribs(i)
+            srt = self._term_contribs(i)[2]
             if len(srt) >= k:
                 theta = max(theta, w * float(srt[k - 1]))
         coll = obs_attrib.active()
@@ -832,25 +869,25 @@ class DeviceEngine:
             bl = np.concatenate([bl, np.zeros(Sp - S, np.int32)])
             cnt = np.concatenate([cnt, np.zeros(Sp - S, np.int32)])
             widf = np.concatenate([widf, np.zeros(Sp - S, np.float32)])
-        k_eff = min(max(k, 0), D)
-        fn = self._blocks_fns.get((Sp, k_eff))
-        if fn is None:
-            fn = self._blocks_fns[(Sp, k_eff)] = _make_bm25_blocks(
-                k_eff, self._block_size)
-        ids, vals = fn(self._d_blk_first, self._d_blk_width,
-                       self._d_blk_woff, self._d_post_words,
-                       self._d_blk_tf_width, self._d_blk_tf_woff,
-                       self._d_tf_words, bl, cnt, widf,
-                       doc_lens_d, avgdl32)
+        def run(kk: int):
+            fn = self._blocks_fns.get((Sp, kk))
+            if fn is None:
+                fn = self._blocks_fns[(Sp, kk)] = _make_bm25_blocks(
+                    kk, self._block_size)
+            return fn(self._d_blk_first, self._d_blk_width,
+                      self._d_blk_woff, self._d_post_words,
+                      self._d_blk_tf_width, self._d_blk_tf_woff,
+                      self._d_tf_words, bl, cnt, widf,
+                      doc_lens_d, avgdl32)
+
         self.planner.note_ranked(mode, S, nb_total - S, 0)
-        ids, vals = np.asarray(ids), np.asarray(vals)
-        return [(int(d), float(s)) for d, s in zip(ids, vals)
-                if s > 0.0]
+        return self._exact_top_k(occ, k, D, run)
 
     def top_k_scored(self, batch, k: int) -> list[tuple[int, float]]:
         """BM25-ranked ``(doc_id, score)``, best first, ties by doc id —
-        the device mirror of ``Engine.top_k_scored`` (float32 on
-        device, so scores agree with the host to ~1e-6 relative).  On a
+        the device mirror of ``Engine.top_k_scored``: the device ranks
+        in float32 and :meth:`_exact_top_k` rescores its candidates, so
+        the answer is byte-equal to the host engine's.  On a
         v2.1 artifact the planner can swap the whole-term windows for a
         survivor-block window (:meth:`_top_k_scored_pruned`)."""
         with self._ops.time("top_k_scored"):
@@ -879,19 +916,16 @@ class DeviceEngine:
                 dfv = np.concatenate([dfv, np.zeros(pad, np.int32)])
             n = np.where(found, dfv, 0).astype(np.int32)
             width = self._tier(int(n.max()) if len(n) else 1)
-            k_eff = min(max(k, 0), D)
             if self._fmt >= artifact_mod.VERSION_V2:
                 cols = self._decode_cols + (
                     self._d_blk_tf_width, self._d_blk_tf_woff,
                     self._d_tf_words)
             else:
                 cols = self._decode_cols
-            ids, vals = self._bm25_fn(T, width, k_eff)(
-                *cols, idx.astype(np.int32), n, found, doc_lens,
-                ndocs, avgdl)
-            ids, vals = np.asarray(ids), np.asarray(vals)
-            return [(int(d), float(s))
-                    for d, s in zip(ids, vals) if s > 0.0]
+            return self._exact_top_k(
+                occ, k, D, lambda kk: self._bm25_fn(T, width, kk)(
+                    *cols, idx.astype(np.int32), n, found, doc_lens,
+                    ndocs, avgdl))
 
     # -- bookkeeping ----------------------------------------------------
 
@@ -928,9 +962,9 @@ class DeviceEngine:
             "ops": self.op_stats(),
             "planner": self.planner.describe(),
             "device": {
-                "platform": jax.default_backend(),
+                **device.identity(),
                 "shards": self._num_shards,
-                "devices": [str(d) for d in self._mesh.devices.ravel()],
+                "devices": self._placed_on,
                 "tiers": self._tiers,
                 "max_prefix_group": self._group,
                 **self.compile_stats(),

@@ -224,7 +224,7 @@ class Engine:
         if hit is None:
             _, ndocs, _ = self._bm25_corpus()
             dfi = self._scoring_df(i, int(self._df[i]))
-            hit = float(np.log(1.0 + (ndocs - dfi + 0.5) / (dfi + 0.5)))
+            hit = float(bm25_idf(dfi, ndocs))
             if len(self._idf_memo) >= self._memo_cap:
                 self._idf_memo.clear()
             self._idf_memo[i] = hit
@@ -824,15 +824,12 @@ class Engine:
         if hit is not None:
             return hit
         doc_lens, ndocs, avgdl = self._bm25_corpus()
-        k1, b = BM25_K1, BM25_B
         # int64 up front: fancy indexing with int32 index arrays pays a
         # per-query widening conversion that doubles its cost
         docs = self.postings_by_index(i).astype(np.int64)
         tf = self.tf_by_index(i).astype(np.float64)
-        dfi = self._scoring_df(i, len(docs))
-        idf = np.log(1.0 + (ndocs - dfi + 0.5) / (dfi + 0.5))
-        denom = tf + k1 * (1.0 - b + b * doc_lens[docs] / avgdl)
-        contrib = idf * tf * (k1 + 1.0) / denom
+        idf = bm25_idf(self._scoring_df(i, len(docs)), ndocs)
+        contrib = bm25_contrib(idf, tf, doc_lens[docs], avgdl)
         docs.setflags(write=False)
         contrib.setflags(write=False)
         srt = np.sort(contrib)[::-1]
@@ -848,8 +845,7 @@ class Engine:
         if hit is not None:
             return hit
         doc_lens, ndocs, avgdl = self._bm25_corpus()
-        dfi = self._scoring_df(i, int(self._df[i]))
-        idf = np.log(1.0 + (ndocs - dfi + 0.5) / (dfi + 0.5))
+        idf = bm25_idf(self._scoring_df(i, int(self._df[i])), ndocs)
         ubs = planner_mod.block_upper_bounds(
             self.artifact, i, idf, avgdl, BM25_K1, BM25_B)
         if len(self._bound_memo) >= self._memo_cap:
@@ -877,11 +873,8 @@ class Engine:
         docs = ids[mask].astype(np.int64)
         tf = tfm[mask].astype(np.float64)
         doc_lens, ndocs, avgdl = self._bm25_corpus()
-        k1, b = BM25_K1, BM25_B
-        dfi = self._scoring_df(i, int(self._df[i]))
-        idf = np.log(1.0 + (ndocs - dfi + 0.5) / (dfi + 0.5))
-        denom = tf + k1 * (1.0 - b + b * doc_lens[docs] / avgdl)
-        return docs, idf * tf * (k1 + 1.0) / denom
+        idf = bm25_idf(self._scoring_df(i, int(self._df[i])), ndocs)
+        return docs, bm25_contrib(idf, tf, doc_lens[docs], avgdl)
 
     def _top_k_pruned(self, occ: list[int], k: int, mode: str,
                       coll=None) -> list[tuple[int, float]]:
@@ -1140,6 +1133,21 @@ PROBE_BATCH_MIN = 8192
 BM25_K1 = 1.2
 BM25_B = 0.75
 
+
+def bm25_idf(dfi, ndocs):
+    """BM25 idf of a term in ``dfi`` of ``ndocs`` documents (float64)."""
+    return np.log(1.0 + (ndocs - dfi + 0.5) / (dfi + 0.5))
+
+
+def bm25_contrib(idf, tf, doc_len, avgdl):
+    """One term's BM25 contribution at each of its postings (float64).
+
+    The one expression every exact scorer evaluates — the host engine's
+    exhaustive and pruned paths and the device engine's rescoring — so
+    their scores stay bit-equal."""
+    denom = tf + BM25_K1 * (1.0 - BM25_B + BM25_B * doc_len / avgdl)
+    return idf * tf * (BM25_K1 + 1.0) / denom
+
 SCORE_CHOICES = ("df", "bm25")
 SCORE_ENV = "MRI_SERVE_SCORE"
 
@@ -1225,7 +1233,6 @@ class AutoEngine:
         self._cache_terms = cache_terms
         self._shards = shards
         self._device = None
-        self._device_failed = False
         cross = envknobs.get(CROSSOVER_ENV)
         self._fixed = None if cross is None else max(int(cross), 0)
         self._measured: int | None = None
@@ -1262,23 +1269,19 @@ class AutoEngine:
     # -- routing --------------------------------------------------------
 
     def _get_device(self):
-        if self._device is None and not self._device_failed:
-            try:
-                from .device_engine import DeviceEngine
-                self._device = DeviceEngine(
-                    self._path, cache_terms=self._cache_terms,
-                    shards=self._shards)
-            except Exception:
-                self._device_failed = True
+        """The device engine, built on first use; a failure to build it
+        (no device, a chip another process holds) propagates."""
+        if self._device is None:
+            from .device_engine import DeviceEngine
+            self._device = DeviceEngine(
+                self._path, cache_terms=self._cache_terms,
+                shards=self._shards)
         return self._device
 
     def _run_probe(self, batch) -> None:
         """Race both engines on this batch, best-of-3 each, once."""
         import time
         dev = self._get_device()
-        if dev is None:
-            self._measured = 1 << 62
-            return
         host_s = dev_s = float("inf")
         for eng in (self._host, dev):
             eng.df(batch)  # warm caches / compile
@@ -1301,18 +1304,14 @@ class AutoEngine:
         n = len(batch)
         if self._fixed is not None:
             if self._fixed > 0 and n >= self._fixed:
-                dev = self._get_device()
-                if dev is not None:
-                    return dev
+                return self._get_device()
             return self._host
-        if n < PROBE_BATCH_MIN or self._device_failed:
+        if n < PROBE_BATCH_MIN:
             return self._host
         if self._measured is None:
             self._run_probe(batch)
-        if self._measured is not None and n >= self._measured:
-            dev = self._get_device()
-            if dev is not None:
-                return dev
+        if n >= self._measured:
+            return self._get_device()
         return self._host
 
     # -- query API ------------------------------------------------------

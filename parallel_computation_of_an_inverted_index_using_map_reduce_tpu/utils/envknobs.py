@@ -454,33 +454,9 @@ declare("MRI_OBS_LOG_RATE_LIMIT", int, 200,
         scope="obs", minimum=0)
 
 # -- benchmarks -------------------------------------------------------
-declare("MRI_TPU_BENCH_ATTEMPTS", int, 3,
-        "Attempts per bench probe before recording a failure.",
-        scope="bench")
-declare("MRI_TPU_BENCH_TIMEOUTS", str, "480,300,240",
-        "Comma list of per-attempt bench timeouts in seconds.",
-        scope="bench")
 declare("MRI_TPU_BENCH_CORPUS", str, None,
         "Corpus directory override for the e2e bench legs.",
         scope="bench")
-declare("MRI_TPU_BENCH_PLATFORM", str, None,
-        "Force a JAX platform for bench subprocesses (e.g. cpu).",
-        scope="bench")
-declare("MRI_TPU_BENCH_PROBE_S", int, 75,
-        "SIGALRM deadline for the e2e bench probe.", scope="bench")
-declare("MRI_TPU_GRID_PROBE_S", int, 240,
-        "SIGALRM deadline for the (mappers, reducers) grid probe.",
-        scope="bench")
-declare("MRI_TPU_KERNEL_PROBE_S", int, 90,
-        "SIGALRM deadline for the Pallas kernel probe.", scope="bench")
-declare("MRI_TPU_DEVTOK_PROBE_S", int, 240,
-        "SIGALRM deadline for the device-tokenizer probe.",
-        scope="bench")
-declare("MRI_TPU_BENCH_ATTEST", str, None,
-        "Attestation file path (default: BENCH_ATTEST.json next to "
-        "bench.py).", scope="bench")
-declare("MRI_TPU_SCALE_PLATFORM", str, None,
-        "Force a JAX platform for the scale bench.", scope="bench")
 declare("MRI_TPU_SCALE_DOCS", int, 1_000_000,
         "Synthetic corpus size for the scale bench.", scope="bench")
 declare("MRI_TPU_SCALE_VOCAB", int, 100_000,
@@ -575,6 +551,3 @@ declare("MRI_SPILL_KILL_AFTER", int, None,
 declare("MRI_TPU_STREAM_CRASH_AFTER_WINDOWS", int, 0,
         "Crash hook: die at a deterministic device-stream position "
         "(0: disabled).", scope="test")
-declare("MRI_TPU_TESTS_ON_TPU", str, "",
-        "Truthy: run the test suite against the real chip instead of "
-        "the forced-CPU default.", scope="test")

@@ -183,7 +183,7 @@ def test_stream_checkpoint_kill_and_resume(tmp_path, monkeypatch):
     """VERDICT r3 #3: kill a checkpointed stream mid-run, resume, and
     get byte-identical output.  The injected crash reproduces the
     round-3 on-chip failure mode (TPU worker died ~9 min into the
-    1M-doc stream, SCALE_r03.json) at a deterministic window."""
+    1M-doc stream, round 3) at a deterministic window."""
     docs = zipf_corpus(num_docs=40, vocab_size=120, tokens_per_doc=12, seed=9)
     paths = write_corpus(tmp_path / "docs", docs)
     write_manifest(tmp_path / "list.txt", paths)

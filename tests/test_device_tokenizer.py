@@ -380,7 +380,7 @@ def test_device_program_has_no_token_scale_scatter():
     # and NO loops: jnp.searchsorted's default method='scan' lowers to
     # a sequential log2(n) while-loop of dynamic slices, the round-3
     # regression's root cause (702 ms at 2^20 queries into 5.7M on the
-    # v5e, BENCH_TPU_r03.json) — the program must stay loop-free
+    # v5e, round 3) — the program must stay loop-free
     assert 'stablehlo.while' not in text, (
         "a while loop appeared in the device program — most likely a "
         "scan-lowered searchsorted crept back in; use "

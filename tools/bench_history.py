@@ -36,7 +36,7 @@ END = "<!-- benchhistory:end -->"
 
 _ROUND_RE = re.compile(r"_r(\d+)\.json$")
 #: files with no _rNN suffix but a known round
-_ROUND_OVERRIDES = {"BENCH_ATTEST.json": 3}
+_ROUND_OVERRIDES: dict[str, int] = {}
 
 #: per-file "ratio to the round's own baseline" text, where the file's
 #: schema carries one (lambda: full doc dict, headline line dict)
