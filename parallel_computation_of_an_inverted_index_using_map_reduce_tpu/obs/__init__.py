@@ -2,7 +2,12 @@
 
 - :mod:`.metrics` — thread-safe Counter/Gauge/Histogram + Registry
   with Prometheus text exposition (stdlib-only, standalone-loadable).
-- :mod:`.timing` — OpTimer / PhaseTimer unified over the histogram.
+- :mod:`.timing` — OpTimer / PhaseTimer unified over the histogram;
+  each span is also a ``jax.profiler.TraceAnnotation`` (where JAX is
+  already imported), so it reaches the profiler's trace under a stable
+  name: ``build.<phase>``, ``build.pack``, ``serve.op.<op>``,
+  ``serve.step.device``, ``serve.step.rescore``, ``serve.batch``,
+  ``serve.reply``.  Importing this package never imports JAX.
 - :mod:`.tracing` — per-request trace ids, trace ring, slow-query log.
 - :mod:`.attribution` — request-scoped cost collector (the EXPLAIN
   surface) and the crash-dump flight recorder.

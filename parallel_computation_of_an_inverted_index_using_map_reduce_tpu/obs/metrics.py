@@ -261,6 +261,10 @@ KNOWN_METRICS = (
      "End-to-end data-request latency (admission to response enqueue)."),
     ("mri_serve_queue_wait_seconds", "histogram",
      "Time spent waiting in the pending queue before dispatch pop."),
+    ("mri_serve_step_<step>_seconds", "histogram",
+     "Dispatcher spans: `batch` (one executed batch, engine-lock wait "
+     "included), `reply` (one engine-answered reply, from the result in "
+     "hand to the line queued for the writer)."),
     # engine-side caches (per-engine registry)
     ("mri_serve_cache_hits_total", "counter",
      "Postings LRU cache hits."),
@@ -287,6 +291,16 @@ KNOWN_METRICS = (
      "On-disk size of the loaded artifact."),
     ("mri_engine_op_<op>_seconds", "histogram",
      "Per-op engine latency (df, postings, and, or, top_k, ...)."),
+    ("mri_engine_step_<step>_seconds", "histogram",
+     "Device-engine steps inside its ops: `device` (one jitted call "
+     "through the fetch of its result), `rescore` (BM25 host block "
+     "bounds, theta and float64 rescoring)."),
+    ("mri_engine_bm25_memo_hits_total", "counter",
+     "Device-engine per-term BM25 memo lookups (contributions, block "
+     "bounds) that hit."),
+    ("mri_engine_bm25_memo_misses_total", "counter",
+     "Device-engine per-term BM25 memo lookups that missed and decoded "
+     "the term on the host."),
     # query planner (per-engine registry)
     ("mri_planner_ranked_exhaustive_total", "counter",
      "Ranked queries the planner scored exhaustively."),

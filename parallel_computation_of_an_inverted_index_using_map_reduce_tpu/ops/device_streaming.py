@@ -275,22 +275,11 @@ class DeviceStreamEngine:
                 self._acc = _regrow_rows(self._acc, cap=self._cap)
 
     def feed(self, buf: np.ndarray, ends: np.ndarray, ids: np.ndarray,
-             *, tok_count: int, max_len: int, stage_hook=None) -> None:
+             *, tok_count: int, max_len: int) -> None:
         """Tokenize one padded byte window on device and fold its
         unique rows into the accumulator.  ``tok_count`` / ``max_len``
         are the window's host-exact stats (host_token_stats) — the
-        caller has already rejected ``max_len > width``.
-
-        ``stage_hook(name, device_value)``, when given, is called after
-        each stage (``upload``, ``window_rows``, ``merge``) with a
-        device value the hook can fetch-barrier on — so stage
-        attribution tooling (tools/profile_stream_stages.py) times the
-        PRODUCTION path instead of a re-implementation that drifts
-        (advisor r4).  A hooked feed also resolves every in-flight
-        merge count at the end (serialized semantics: the 2-deep
-        pipeline is exactly what the hook's barriers suppress), keeping
-        the capacity-growth path identical to a resolved-count run.
-        Production callers pass nothing and pay nothing."""
+        caller has already rejected ``max_len > width``."""
         if tok_count == 0:
             return
         self.max_word_len = max(self.max_word_len, max_len)
@@ -302,10 +291,6 @@ class DeviceStreamEngine:
         d_buf = jax.device_put(buf)
         d_ends = jax.device_put(ends)
         d_ids = jax.device_put(ids)
-        if stage_hook is not None:
-            # all three uploads: barriering d_buf alone lets the ends /
-            # ids transfers leak into the next stage's measured time
-            stage_hook("upload", (d_buf, d_ends, d_ids))
         rows, counts = window_rows(
             d_buf, d_ends, d_ids,
             width=self._width, tok_cap=tok_cap, num_docs=ends.shape[0],
@@ -313,8 +298,6 @@ class DeviceStreamEngine:
             out_cap=out_cap)
         counts.copy_to_host_async()
         self._window_checks.append((counts, tok_cap, max_len))
-        if stage_hook is not None:
-            stage_hook("window_rows", counts)
         # tighten the host bound against resolved merge counts, read
         # TWO merges late: resolving merge i-2 before dispatching
         # merge i keeps two merges in flight (the previous count sync
@@ -346,12 +329,6 @@ class DeviceStreamEngine:
         inj = faults.active()
         if inj is not None:
             inj.on_stream_window(self.windows_fed)
-        if stage_hook is not None:
-            stage_hook("merge", pending_count)
-            while self._pending:
-                handle, _ = self._pending.pop(0)
-                self._unique_bound = int(np.asarray(handle))
-                self.rows_curve.append(self._unique_bound)
 
     def _verify_window_checks(self) -> None:
         """Fetch + verify the accumulated per-window device stats

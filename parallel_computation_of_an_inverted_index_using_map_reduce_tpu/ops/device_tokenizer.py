@@ -113,9 +113,9 @@ def _tokenize_front(data, doc_ends, doc_id_values, *, tok_cap: int,
     n = data.shape[0]
     # byte classifiers as arithmetic, not 256-entry table gathers: a
     # token-scale gather costs ~7 ms/2^20 rows on the v5e where the
-    # compare chain fuses for free (round-3 attribution,
-    # tools/attribute_device_stages.py — the two table lookups were
-    # ~100 ms of the program).  Exact C-locale contract of
+    # compare chain fuses for free (round-3 attribution on the v5e:
+    # the two table lookups were ~100 ms of the program).  Exact
+    # C-locale contract of
     # native/tokenizer.cc ByteTables: space = {0x20, 0x09..0x0D};
     # A-Z|0x20 lands in [a-z] and no non-letter byte does (the only
     # preimages of [0x61,0x7A] under |0x20 are the two letter ranges).

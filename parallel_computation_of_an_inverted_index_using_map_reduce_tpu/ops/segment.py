@@ -33,7 +33,7 @@ def searchsorted_device(a, v):
 
     ``jnp.searchsorted``'s default ``method='scan'`` binary search
     lowers to a sequential log2(n)-step loop of dynamic slices —
-    measured on the v5e (round 3, tools/profile_device_stages.py):
+    measured on the v5e (round 3):
     173 ms for 2^20 sorted queries into a 2^20 array, 702 ms into a
     5.7M array.  Three of those per run dominated the all-device
     engine's 1157 ms device_index regression.

@@ -129,7 +129,12 @@ may carry a ``trace_id`` (auto-generated under ``MRI_OBS_ENABLE``)
 which is echoed on the response; each finished request records
 contiguous queue-wait → coalesce → engine spans into a bounded ring
 (the ``trace`` op) and requests slower than ``MRI_OBS_SLOW_MS`` emit
-one structured JSON line on the ``mri_tpu.obs`` logger.
+one structured JSON line on the ``mri_tpu.obs`` logger.  The
+dispatcher's own time is two spans on the registry (``stats()["steps"]``,
+beside ``uptime_s``) and on the profiler's clock: ``serve.batch``, one
+executed batch with its engine-lock wait, and ``serve.reply``, one
+engine-answered reply from the result in hand to the line queued for
+the writer.
 
 Cost attribution: a data request carrying ``"explain": true`` runs
 SOLO (outside the coalesced df/postings groups, so its costs are its
@@ -163,6 +168,7 @@ from ..obs import attribution as obs_attrib
 from ..obs import logging as obs_logging
 from ..obs import metrics as obs_metrics
 from ..obs import slo as obs_slo
+from ..obs import timing as obs_timing
 from ..obs import tracing as obs_tracing
 from ..obs import watchdog as obs_watchdog
 from ..obs import windows as obs_windows
@@ -711,6 +717,10 @@ class ServeDaemon:
             self.registry.histogram("mri_serve_request_seconds")
         self._h_queue_wait = \
             self.registry.histogram("mri_serve_queue_wait_seconds")
+        # the dispatcher's spans: ``batch`` and ``reply`` (stats "steps")
+        self._steps = obs_timing.OpTimer(
+            registry=self.registry, prefix="mri_serve_step", span="serve")
+        self._t_start: float | None = None  # monotonic, set by start()
         self._count_lock = threading.Lock()
         self._obs_enabled = obs_tracing.enabled()
         self._slow_ms = obs_tracing.slow_ms()
@@ -801,6 +811,7 @@ class ServeDaemon:
         self._listener = ls
         self._host, self._port = ls.getsockname()[:2]
         self._lease_owner = f"{self._host}:{self._port}#{os.getpid()}"
+        self._t_start = time.monotonic()
         self._watchdog.register("dispatcher")
         self._watchdog.register("accept")
         self._rolling.start()
@@ -1393,13 +1404,35 @@ class ServeDaemon:
                 if not kept:
                     continue
                 batch = kept
-            self._execute(batch)
+            # one batch, engine-lock wait included
+            with self._steps.time("batch"):
+                self._execute(batch)
 
     def _finish(self, item: _Request, payload: dict, *,
                 admitted: bool = True) -> None:
         """The one response for an admitted request (ok or error)."""
+        if self._respond(item, payload):
+            self._settle(item, payload, admitted=admitted)
+
+    def _answer(self, item: _Request, raw, explain: dict | None = None
+                ) -> None:
+        """Reply to an engine-answered request, on the dispatcher.  The
+        ``serve.reply`` span runs from the engine's result ``raw`` in
+        hand to the line queued for the writer: encoding, payload,
+        ``json.dumps`` and the put."""
+        with self._steps.time("reply"):
+            payload = self._payload(item, raw)
+            if explain is not None:
+                payload["explain"] = explain
+            queued = self._respond(item, payload)
+        if queued:
+            self._settle(item, payload, admitted=True)
+
+    def _respond(self, item: _Request, payload: dict) -> bool:
+        """Queue the request's one response line; False when it was
+        already answered."""
         if item.done:
-            return
+            return False
         item.done = True
         if item.tstate is not None:
             err = payload.get("error")
@@ -1420,6 +1453,12 @@ class ServeDaemon:
         if item.trace_id is not None:
             payload.setdefault("trace_id", item.trace_id)
         item.conn.enqueue(item.seq, payload)
+        return True
+
+    def _settle(self, item: _Request, payload: dict, *,
+                admitted: bool) -> None:
+        """After the response is queued: connection and in-flight
+        bookkeeping, latency histograms and the trace record."""
         with item.conn.lock:
             item.conn.pending -= 1
             idle = item.conn.read_eof and item.conn.pending == 0
@@ -1563,26 +1602,13 @@ class ServeDaemon:
                 try:
                     terms = [t for it in group for t in it.terms]
                     batch = eng.encode_batch(terms)
-                    if op == "df":
-                        out = eng.df(batch)
-                        pos = 0
-                        for it in group:
-                            n = len(it.terms)
-                            self._finish(it, {
-                                "ok": True,
-                                "df": out[pos:pos + n].tolist()})
-                            pos += n
-                    else:
-                        runs = eng.postings(batch)
-                        pos = 0
-                        for it in group:
-                            n = len(it.terms)
-                            part = runs[pos:pos + n]
-                            self._finish(it, {
-                                "ok": True,
-                                "postings": [r.tolist() if r is not None
-                                             else None for r in part]})
-                            pos += n
+                    out = eng.df(batch) if op == "df" \
+                        else eng.postings(batch)
+                    pos = 0
+                    for it in group:
+                        n = len(it.terms)
+                        self._answer(it, out[pos:pos + n])
+                        pos += n
                 except Exception as e:  # group failed: every unanswered
                     for it in group:    # member gets a counted internal
                         if not it.done:
@@ -1611,9 +1637,7 @@ class ServeDaemon:
                             [eng.encode_batch(it.terms)
                              for it in group], k)
                         for it, top in zip(group, tops):
-                            self._finish(it, {
-                                "ok": True,
-                                "docs": [[d, s] for d, s in top]})
+                            self._answer(it, top)
                     except Exception as e:
                         for it in group:
                             if not it.done:
@@ -1627,7 +1651,7 @@ class ServeDaemon:
                     if it.explain:
                         with obs_attrib.collect(it.op) as coll:
                             t_eng = time.monotonic()
-                            payload = self._exec_one(eng, it)
+                            raw = self._exec_one(eng, it)
                         coll.stage("queue",
                                    (it.t_pop - it.t_admit) * 1e6)
                         coll.stage("coalesce",
@@ -1635,33 +1659,27 @@ class ServeDaemon:
                         coll.stage("engine",
                                    (time.monotonic() - t_eng) * 1e6)
                         it.attrib = coll
-                        payload["explain"] = coll.report()
+                        self._answer(it, raw, explain=coll.report())
                     else:
-                        payload = self._exec_one(eng, it)
-                    self._finish(it, payload)
+                        self._answer(it, self._exec_one(eng, it))
                 except Exception as e:
                     self._count("internal_errors")
                     self._finish(it, {"error": "internal",
                                       "detail": str(e)})
 
-    def _exec_one(self, eng, it: _Request) -> dict:
-        """One data request against the engine; returns the ok payload.
-        df/postings normally ride the coalesced group path — they land
-        here solo when the request asked for an explain report."""
+    def _exec_one(self, eng, it: _Request):
+        """One data request against the engine; returns the engine's
+        result, which :meth:`_payload` encodes.  df/postings normally
+        ride the coalesced group path — they land here solo when the
+        request asked for an explain report."""
         if it.op == "df":
-            out = eng.df(eng.encode_batch(it.terms))
-            return {"ok": True, "df": out.tolist()}
+            return eng.df(eng.encode_batch(it.terms))
         if it.op == "postings":
-            runs = eng.postings(eng.encode_batch(it.terms))
-            return {"ok": True,
-                    "postings": [r.tolist() if r is not None else None
-                                 for r in runs]}
+            return eng.postings(eng.encode_batch(it.terms))
         if it.op == "and":
-            docs = eng.query_and(eng.encode_batch(it.terms))
-            return {"ok": True, "docs": docs.tolist()}
+            return eng.query_and(eng.encode_batch(it.terms))
         if it.op == "or":
-            docs = eng.query_or(eng.encode_batch(it.terms))
-            return {"ok": True, "docs": docs.tolist()}
+            return eng.query_or(eng.encode_batch(it.terms))
         if it.op == "top_k" and it.score == "bm25":
             top = eng.top_k_scored(eng.encode_batch(it.terms), it.k)
             planner = getattr(eng, "planner", None)
@@ -1669,11 +1687,25 @@ class ServeDaemon:
                 # decision + pruning counters ride the trace record so
                 # slow ranked queries are attributable to their strategy
                 it.planner = planner.last_ranked
-            return {"ok": True, "docs": [[d, s] for d, s in top]}
-        top = eng.top_k(it.letter, it.k)  # top_k by df
+            return top
+        return eng.top_k(it.letter, it.k)  # top_k by df
+
+    @staticmethod
+    def _payload(it: _Request, raw) -> dict:
+        """The ok payload of an engine result for request ``it``."""
+        if it.op == "df":
+            return {"ok": True, "df": raw.tolist()}
+        if it.op == "postings":
+            return {"ok": True,
+                    "postings": [r.tolist() if r is not None else None
+                                 for r in raw]}
+        if it.op in ("and", "or"):
+            return {"ok": True, "docs": raw.tolist()}
+        if it.op == "top_k" and it.score == "bm25":
+            return {"ok": True, "docs": [[d, s] for d, s in raw]}
         return {"ok": True,
                 "top": [[t.decode("ascii", "replace"), int(d)]
-                        for t, d in top]}
+                        for t, d in raw]}
 
     # -- live mutations (segment-managed dirs) -------------------------
 
@@ -1956,6 +1988,9 @@ class ServeDaemon:
             "codel": self._codel.state(),
             "result_cache": self._result_cache.stats(),
             "tenants": self._tenant_stats(),
+            "steps": self._steps.stats(),
+            "uptime_s": 0.0 if self._t_start is None
+                        else time.monotonic() - self._t_start,
         }
 
     def _tenant_stats(self) -> dict:

@@ -90,7 +90,7 @@ def _pow2_floor(n: int) -> int:
 def _make_lookup(mesh, nsteps: int, group: int):
     """Jitted fused resolve: (idx, found, df) per query lane."""
 
-    def body(key_hi, key_lo, rows, df, q_hi, q_lo, q_rows):
+    def serve_lookup(key_hi, key_lo, rows, df, q_hi, q_lo, q_rows):
         V = key_hi.shape[0]
 
         def bisect(right: bool):
@@ -124,7 +124,7 @@ def _make_lookup(mesh, nsteps: int, group: int):
         return at.astype(jnp.int32), found, dfv
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh,
+        serve_lookup, mesh=mesh,
         in_specs=(P(), P(), P(), P(),
                   P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
         out_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
@@ -145,11 +145,11 @@ def _decode_window(post_offsets, postings, idx, n, *, width: int):
 
 
 def _make_decode(mesh, width: int):
-    def body(post_offsets, postings, idx, n):
+    def serve_decode(post_offsets, postings, idx, n):
         return _decode_window(post_offsets, postings, idx, n, width=width)
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh,
+        serve_decode, mesh=mesh,
         in_specs=(P(), P(), P(SHARD_AXIS), P(SHARD_AXIS)),
         out_specs=P(SHARD_AXIS), check_vma=False))
 
@@ -221,14 +221,14 @@ def _tf_window_v2(term_block_off, blk_tf_width, blk_tf_woff, tf_words,
 
 
 def _make_decode_v2(mesh, width: int, block_size: int):
-    def body(term_block_off, blk_first, blk_width, blk_woff, post_words,
-             idx, n):
+    def serve_decode(term_block_off, blk_first, blk_width, blk_woff,
+                     post_words, idx, n):
         return _decode_window_v2(
             term_block_off, blk_first, blk_width, blk_woff, post_words,
             idx, n, width=width, block_size=block_size)
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh,
+        serve_decode, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(),
                   P(SHARD_AXIS), P(SHARD_AXIS)),
         out_specs=P(SHARD_AXIS), check_vma=False))
@@ -247,6 +247,7 @@ def _make_bool(op: str, width: int):
         docs = _decode_window(post_offsets, postings, idx, n, width=width)
         return _bool_tail(op, docs, n, width)
 
+    body.__name__ = f"serve_bool_{op}"
     return jax.jit(body)
 
 
@@ -278,6 +279,7 @@ def _make_bool_v2(op: str, width: int, block_size: int):
             idx, n, width=width, block_size=block_size)
         return _bool_tail(op, docs, n, width)
 
+    body.__name__ = f"serve_bool_{op}"
     return jax.jit(body)
 
 
@@ -301,20 +303,20 @@ def _bm25_tail(docs, tfs, n, found, doc_lens, ndocs, avgdl, width: int,
 
 
 def _make_bm25(width: int, k: int):
-    def body(post_offsets, postings, idx, n, found, doc_lens, ndocs,
-             avgdl):
+    def serve_bm25(post_offsets, postings, idx, n, found, doc_lens, ndocs,
+                   avgdl):
         docs = _decode_window(post_offsets, postings, idx, n, width=width)
         tfs = jnp.ones(docs.shape, jnp.int32)  # v1: no tf column
         return _bm25_tail(docs, tfs, n, found, doc_lens, ndocs, avgdl,
                           width, k)
 
-    return jax.jit(body)
+    return jax.jit(serve_bm25)
 
 
 def _make_bm25_v2(width: int, k: int, block_size: int):
-    def body(term_block_off, blk_first, blk_width, blk_woff, post_words,
-             blk_tf_width, blk_tf_woff, tf_words, idx, n, found,
-             doc_lens, ndocs, avgdl):
+    def serve_bm25(term_block_off, blk_first, blk_width, blk_woff,
+                   post_words, blk_tf_width, blk_tf_woff, tf_words, idx, n,
+                   found, doc_lens, ndocs, avgdl):
         docs = _decode_window_v2(
             term_block_off, blk_first, blk_width, blk_woff, post_words,
             idx, n, width=width, block_size=block_size)
@@ -324,7 +326,7 @@ def _make_bm25_v2(width: int, k: int, block_size: int):
         return _bm25_tail(docs, tfs, n, found, doc_lens, ndocs, avgdl,
                           width, k)
 
-    return jax.jit(body)
+    return jax.jit(serve_bm25)
 
 
 def _make_bm25_blocks(k: int, block_size: int):
@@ -338,9 +340,9 @@ def _make_bm25_blocks(k: int, block_size: int):
     ``lax.top_k``s the dense column.  Padded rows carry ``cnt == 0``
     and contribute nothing."""
 
-    def body(blk_first, blk_width, blk_woff, post_words,
-             blk_tf_width, blk_tf_woff, tf_words,
-             bl, cnt, widf, doc_lens, avgdl):
+    def serve_bm25_blocks(blk_first, blk_width, blk_woff, post_words,
+                          blk_tf_width, blk_tf_woff, tf_words,
+                          bl, cnt, widf, doc_lens, avgdl):
         lane = jnp.arange(block_size, dtype=jnp.int32)
         w = blk_width[bl][:, None]
         off = jnp.maximum(lane - 1, 0)[None, :] * w
@@ -364,15 +366,15 @@ def _make_bm25_blocks(k: int, block_size: int):
         svals, ids = jax.lax.top_k(scores, k)
         return ids, svals
 
-    return jax.jit(body)
+    return jax.jit(serve_bm25_blocks)
 
 
 def _make_topk(k: int):
-    def body(df_order, df, lo):
+    def serve_topk_df(df_order, df, lo):
         pick = jax.lax.dynamic_slice(df_order, (lo,), (k,))
         return pick, df[pick]
 
-    return jax.jit(body)
+    return jax.jit(serve_topk_df)
 
 
 class DeviceEngine:
@@ -479,6 +481,11 @@ class DeviceEngine:
         self._cache = LRUCache(cache_terms, registry=self.metrics,
                                prefix="mri_serve_cache")  # idle on the device path
         self._ops = OpTimer(registry=self.metrics)
+        # two disjoint steps inside the ops: every jitted call through
+        # the fetch of its result, and the BM25 host work around them
+        # (kept off ``_ops``: serve.engine_ms sums every op there)
+        self._steps = OpTimer(registry=self.metrics,
+                              prefix="mri_engine_step", span="serve.step")
         # decode-plane counters, host-engine names: the device decodes
         # inside jitted kernels, so the tallies are computed host-side
         # from the artifact's block/offset columns per resolved term
@@ -495,6 +502,10 @@ class DeviceEngine:
         self._score_memo: dict[int, np.ndarray] = {}
         self._bound_memo: dict[int, tuple] = {}
         self._memo_cap = max(int(cache_terms), 1)
+        self._c_memo_hits = \
+            self.metrics.counter("mri_engine_bm25_memo_hits_total")
+        self._c_memo_misses = \
+            self.metrics.counter("mri_engine_bm25_memo_misses_total")
 
     # -- shape bucketing ------------------------------------------------
 
@@ -550,12 +561,10 @@ class DeviceEngine:
                 [rows, np.zeros((Bp - B, self._width), np.uint8)])
             q_hi = np.concatenate([q_hi, np.zeros(Bp - B, np.uint32)])
             q_lo = np.concatenate([q_lo, np.zeros(Bp - B, np.uint32)])
-        idx, found, dfv = self._lookup_fn(
-            self._d_key_hi, self._d_key_lo, self._d_rows, self._d_df,
-            q_hi, q_lo, rows)
-        idx = np.asarray(idx)[:B]
-        found = np.asarray(found)[:B]
-        dfv = np.asarray(dfv)[:B]
+        with self._steps.time("device"):
+            idx, found, dfv = (np.asarray(a)[:B] for a in self._lookup_fn(
+                self._d_key_hi, self._d_key_lo, self._d_rows, self._d_df,
+                q_hi, q_lo, rows))
         coll = obs_attrib.active()
         if coll is not None:
             for t, i, ok, d in zip(q.tolist(), idx.tolist(),
@@ -621,9 +630,10 @@ class DeviceEngine:
                     [part_idx, np.zeros(Bp - L, np.int32)])
                 part_n = np.concatenate(
                     [part_n, np.zeros(Bp - L, np.int32)])
-            win = fn(*self._decode_cols,
-                     part_idx.astype(np.int32), part_n.astype(np.int32))
-            out[at:at + L] = np.asarray(win)[:L]
+            with self._steps.time("device"):
+                win = fn(*self._decode_cols, part_idx.astype(np.int32),
+                         part_n.astype(np.int32))
+                out[at:at + L] = np.asarray(win)[:L]
         return out
 
     def postings(self, batch) -> list[np.ndarray | None]:
@@ -653,10 +663,11 @@ class DeviceEngine:
             fn = self._topk_fns.get(k_eff)
             if fn is None:
                 fn = self._topk_fns[k_eff] = _make_topk(k_eff)
-            pick, dfs = fn(self._d_df_order, self._d_df, np.int32(lo))
+            with self._steps.time("device"):
+                pick, dfs = (np.asarray(a) for a in fn(
+                    self._d_df_order, self._d_df, np.int32(lo)))
             art = self.artifact
-            return [(art.term(int(i)), int(d))
-                    for i, d in zip(np.asarray(pick), np.asarray(dfs))]
+            return [(art.term(int(i)), int(d)) for i, d in zip(pick, dfs)]
 
     def _bool_fn(self, op: str, T: int, width: int):
         fn = self._bool_fns.get((op, T, width))
@@ -685,9 +696,11 @@ class DeviceEngine:
                 uidx = np.concatenate([uidx, np.zeros(pad, np.int32)])
                 n = np.concatenate([n, np.zeros(pad, np.int32)])
         width = self._tier(int(n.max()) if len(n) else 1)
-        out, cnt = self._bool_fn(op, T, width)(
-            *self._decode_cols, uidx.astype(np.int32), n)
-        return np.asarray(out)[:int(cnt)].astype(np.int32)
+        fn = self._bool_fn(op, T, width)
+        with self._steps.time("device"):
+            out, cnt = fn(*self._decode_cols, uidx.astype(np.int32), n)
+            out, cnt = np.asarray(out), int(cnt)
+        return out[:cnt].astype(np.int32)
 
     def query_and(self, batch) -> np.ndarray:
         with self._ops.time("and"):
@@ -733,11 +746,20 @@ class DeviceEngine:
             self._bm25_host = artifact_mod.bm25_corpus(self.artifact)
         return self._bm25_host
 
+    def _note_memo(self, i: int, hit: bool) -> None:
+        """Count one probe of a per-term BM25 memo for term ``i`` on the
+        registry and, beside it, on the attribution collector."""
+        (self._c_memo_hits if hit else self._c_memo_misses).inc()
+        coll = obs_attrib.active()
+        if coll is not None:
+            coll.cache_event(i, hit, "mri_engine_bm25_memo")
+
     def _term_contribs(self, i: int) -> tuple:
         """``(docs, contrib, contrib_sorted_desc)`` for term ``i`` (f64,
         host) — the host engine's shared ``bm25_contrib``, so the exact
         rescoring below is bit-equal to ``Engine._term_scores``."""
         hit = self._score_memo.get(i)
+        self._note_memo(i, hit is not None)
         if hit is not None:
             return hit
         doc_lens, ndocs, avgdl = self._bm25_host_cols()
@@ -763,26 +785,29 @@ class DeviceEngine:
         than float32 error (``_F32_REL``); until then ``kk`` grows."""
         kk = min(D, _next_pow2(k + 16))
         while True:
-            ids, vals = (np.asarray(a) for a in run(kk))
-            cand = ids[vals > 0.0].astype(np.int64)
-            exact = np.zeros(len(cand), np.float64)
-            for i in occ:
-                docs, contrib, _ = self._term_contribs(i)
-                pos = np.minimum(np.searchsorted(docs, cand),
-                                 max(len(docs) - 1, 0))
-                hit = docs[pos] == cand
-                exact[hit] += contrib[pos[hit]]
-            top = np.lexsort((cand, -exact))[:k]
-            complete = (len(cand) < kk or kk >= D or (
-                len(top) == k
-                and exact[top[-1]] > float(vals[-1]) * (1.0 + _F32_REL)))
-            if complete:
-                return [(int(cand[j]), float(exact[j])) for j in top]
+            with self._steps.time("device"):
+                ids, vals = (np.asarray(a) for a in run(kk))
+            with self._steps.time("rescore"):
+                cand = ids[vals > 0.0].astype(np.int64)
+                exact = np.zeros(len(cand), np.float64)
+                for i in occ:
+                    docs, contrib, _ = self._term_contribs(i)
+                    pos = np.minimum(np.searchsorted(docs, cand),
+                                     max(len(docs) - 1, 0))
+                    hit = docs[pos] == cand
+                    exact[hit] += contrib[pos[hit]]
+                top = np.lexsort((cand, -exact))[:k]
+                complete = (len(cand) < kk or kk >= D or (
+                    len(top) == k
+                    and exact[top[-1]] > float(vals[-1]) * (1.0 + _F32_REL)))
+                if complete:
+                    return [(int(cand[j]), float(exact[j])) for j in top]
             kk = min(D, kk * 4)
 
     def _term_bounds(self, i: int) -> tuple:
         """(per-block f64 upper bounds, their max, idf) for term i."""
         hit = self._bound_memo.get(i)
+        self._note_memo(i, hit is not None)
         if hit is not None:
             return hit
         doc_lens, ndocs, avgdl = self._bm25_host_cols()
@@ -812,14 +837,15 @@ class DeviceEngine:
         weight: dict[int, int] = {}
         for i in occ:
             weight[i] = weight.get(i, 0) + 1
-        terms = [(i, w) + self._term_bounds(i)
-                 for i, w in weight.items()]
-        total = sum(w * umax for _i, w, _ubs, umax, _idf in terms)
-        theta = 0.0
-        for i, w, _ubs, _umax, _idf in terms:
-            srt = self._term_contribs(i)[2]
-            if len(srt) >= k:
-                theta = max(theta, w * float(srt[k - 1]))
+        with self._steps.time("rescore"):
+            terms = [(i, w) + self._term_bounds(i)
+                     for i, w in weight.items()]
+            total = sum(w * umax for _i, w, _ubs, umax, _idf in terms)
+            theta = 0.0
+            for i, w, _ubs, _umax, _idf in terms:
+                srt = self._term_contribs(i)[2]
+                if len(srt) >= k:
+                    theta = max(theta, w * float(srt[k - 1]))
         coll = obs_attrib.active()
         if coll is not None:
             coll.theta(theta)
@@ -960,6 +986,9 @@ class DeviceEngine:
             "artifact_bytes": self.artifact.nbytes,
             "cache": self.cache_stats(),
             "ops": self.op_stats(),
+            "steps": self._steps.stats(),
+            "bm25_memo": {"hits": self._c_memo_hits.value,
+                          "misses": self._c_memo_misses.value},
             "planner": self.planner.describe(),
             "device": {
                 **device.identity(),

@@ -40,6 +40,18 @@ def _write_letter_atomic(path: Path, payload: bytes) -> None:
     os.replace(tmp, path)
 
 
+def _timed_pack(pack) -> dict:
+    """Run ``pack()``, which writes ``index.mri`` and returns its size,
+    under the ``build.pack`` span; the artifact stats a build reports."""
+    from ..obs.timing import PhaseTimer
+
+    timer = PhaseTimer()
+    with timer.phase("pack"):
+        nbytes = pack()
+    return {"artifact_bytes": int(nbytes),
+            "artifact_build_ms": round(timer.phases["pack"] * 1e3, 3)}
+
+
 def _maybe_kill_after(letters_done: int) -> None:
     # Crash-injection hook for the kill-mid-emit durability test: after
     # N complete letter files, die without unwinding (SIGKILL — no
@@ -91,17 +103,11 @@ def emit_index(
     def _pack_artifact() -> dict:
         if artifact_path is None:
             return {}
-        import time
-
         from ..serve import artifact as artifact_mod
 
-        t0 = time.perf_counter()
-        nbytes = artifact_mod.build_from_emit_arrays(
+        return _timed_pack(lambda: artifact_mod.build_from_emit_arrays(
             artifact_path, vocab=np.asarray(vocab), order=order, df=df,
-            offsets=offsets, postings=postings, max_doc_id=max_doc_id)
-        return {"artifact_bytes": int(nbytes),
-                "artifact_build_ms": round(
-                    (time.perf_counter() - t0) * 1e3, 3)}
+            offsets=offsets, postings=postings, max_doc_id=max_doc_id))
 
     if backend in ("auto", "native"):
         from .. import native
@@ -187,11 +193,7 @@ def emit_grouped(output_dir: str | Path,
         _maybe_kill_after(letter + 1)
     if artifact_path is None:
         return {}
-    import time
-
     from ..serve import artifact as artifact_mod
 
-    t0 = time.perf_counter()
-    nbytes = artifact_mod.build_from_grouped(artifact_path, per_letter)
-    return {"artifact_bytes": int(nbytes),
-            "artifact_build_ms": round((time.perf_counter() - t0) * 1e3, 3)}
+    return _timed_pack(
+        lambda: artifact_mod.build_from_grouped(artifact_path, per_letter))

@@ -2,7 +2,7 @@
 
 :func:`setup` places JAX's persistent compilation cache and starts
 counting backend compile seconds; ``cli.main``, ``bench.py``'s child and
-``tools/measure_tpu.py`` call it before their first compile.  The cache
+the benchmark's drivers call it before their first compile.  The cache
 goes where ``JAX_COMPILATION_CACHE_DIR`` says when that is set (JAX reads
 the variable itself, and nothing else is set here); otherwise it goes to
 ``<checkout>/.jax_cache``, a fixed path, because the cache key includes

@@ -4,7 +4,7 @@ Times the overlap plan's host components in isolation on this machine —
 native scan, u16 feed assembly, df snapshots, finalize, emit-order
 lexsort, run-meta tables, native multi-run emit — so the optimization
 targets are measured, not guessed.  Device RTT is excluded on purpose
-(run on the cpu platform); on-chip e2e comes from tools/measure_tpu.py.
+(run on the cpu platform); on-chip e2e comes from ``python3 -m benchmark``.
 
     python tools/profile_host.py [--threads N] [--reps R]
 """
