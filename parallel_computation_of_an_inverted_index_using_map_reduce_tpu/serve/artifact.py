@@ -353,19 +353,53 @@ def _write(path, buf: np.ndarray, *, width: int, vocab: int,
     return len(buf)
 
 
-def _pack_bits(vals: np.ndarray, w: int) -> np.ndarray:
-    """Pack ``vals`` (< 2**w each) at ``w`` bits LSB-first into a
-    word-aligned little-endian uint8 array (the C++ BitPacker's wire
-    form; width 0 packs to nothing)."""
-    if w == 0 or not len(vals):
+#: 2**0 .. 2**62: an int64's bit length is how many of these it reaches.
+_POW2 = np.left_shift(1, np.arange(63, dtype=np.int64))
+
+
+def _bit_length(v: np.ndarray) -> np.ndarray:
+    """``int.bit_length`` of each int64, exactly (0 for v <= 0)."""
+    return np.searchsorted(_POW2, v, side="right").astype(np.uint8)
+
+
+def _pack_blocks(vals: np.ndarray, counts: np.ndarray,
+                 widths: np.ndarray) -> np.ndarray:
+    """Bit-pack consecutive blocks of ``vals`` in whole-array passes.
+
+    Block b is the next ``counts[b]`` values, each kept to its low
+    ``widths[b]`` bits and packed LSB-first into little-endian u32
+    words from its own word boundary (the C++ BitPacker's wire form;
+    width 0 packs to nothing).  A value lands in one word, or spills
+    into the next when ``shift + width > 32``; no two pieces share a
+    bit, so summing them per word is an OR (exact in float64, since
+    every word stays below 2**32).
+    """
+    counts = counts.astype(np.int64)
+    widths = widths.astype(np.int64)
+    nwords = (counts * widths + 31) >> 5
+    total = int(nwords.sum())
+    if not total:
         return np.zeros(0, dtype=np.uint8)
-    bits = np.unpackbits(
-        np.ascontiguousarray(vals, dtype="<u4").view(np.uint8).reshape(-1, 4),
-        axis=1, bitorder="little")[:, :w].ravel()
-    pad = (-len(bits)) % 32
-    if pad:
-        bits = np.concatenate([bits, np.zeros(pad, dtype=np.uint8)])
-    return np.packbits(bits, bitorder="little")
+    woff = np.cumsum(nwords) - nwords
+    voff = np.cumsum(counts) - counts
+    w = np.repeat(widths, counts)
+    # value i of the run sits at bit woff*32 + (i - voff) * w
+    bit = np.repeat((woff << 5) - voff * widths, counts)
+    bit += np.arange(len(w), dtype=np.int64) * w
+    word = bit >> 5
+    shift = (bit & 31).astype(np.uint64)
+    del bit
+    w = w.astype(np.uint64)
+    v = vals.astype(np.uint64)
+    v &= (np.uint64(1) << w) - np.uint64(1)
+    out = np.bincount(word, weights=(v << shift) & np.uint64(0xFFFFFFFF),
+                      minlength=total + 1)
+    spill = shift + w > np.uint64(32)
+    out += np.bincount(
+        word[spill] + 1,
+        weights=v[spill] >> (np.uint64(32) - shift[spill]),
+        minlength=total + 1)
+    return out[:total].astype("<u4").view(np.uint8)
 
 
 def pack_v2(path, *, term_blob: np.ndarray, term_offsets: np.ndarray,
@@ -376,8 +410,9 @@ def pack_v2(path, *, term_blob: np.ndarray, term_offsets: np.ndarray,
             block_size: int | None = None, fmt: int | None = None,
             score_bits: int | None = None) -> int:
     """Write a format-v2/v2.1 artifact from lex-order ABSOLUTE postings
-    (the pure-Python packer — the cpu backend's merge handle has a
-    one-pass native equivalent in :func:`build_from_merge`).
+    (whole-array numpy passes over every block at once — the cpu
+    backend's merge handle has a one-pass native equivalent in
+    :func:`build_from_merge`).
 
     ``tf`` aligns with ``postings`` (defaults to all-ones); ``doc_lens``
     defaults to each doc's tf sum, so scoring stays self-consistent for
@@ -415,41 +450,30 @@ def pack_v2(path, *, term_blob: np.ndarray, term_offsets: np.ndarray,
         out[:len(doc_lens)] = doc_lens[:max_doc_id + 1]
         doc_lens = out
 
-    blk_max: list[int] = []
-    blk_first: list[int] = []
-    blk_width: list[int] = []
-    blk_tf_width: list[int] = []
-    blk_max_tf: list[int] = []
-    blk_min_dl: list[int] = []
-    post_parts: list[np.ndarray] = []
-    tf_parts: list[np.ndarray] = []
-    cap = (1 << bits) - 1 if bits else 0
-    for t in range(vocab):
-        lo, hi = int(post_offsets[t]), int(post_offsets[t + 1])
-        for b0 in range(lo, hi, B):
-            b1 = min(b0 + B, hi)
-            docs = postings[b0:b1].astype(np.int64)
-            tfs = tf[b0:b1].astype(np.int64)
-            blk_first.append(int(docs[0]))
-            blk_max.append(int(docs[-1]))
-            deltas = np.diff(docs) - 1
-            w = int(deltas.max()).bit_length() if len(deltas) and \
-                deltas.max() > 0 else 0
-            tw = int(tfs.max() - 1).bit_length() if tfs.max() > 1 else 0
-            blk_width.append(w)
-            blk_tf_width.append(tw)
-            post_parts.append(_pack_bits(deltas, w))
-            tf_parts.append(_pack_bits(tfs - 1, tw))
-            if bits:
-                # saturated integer columns (never floats: the native
-                # exporter must reproduce these bytes exactly)
-                blk_max_tf.append(min(int(tfs.max()), cap))
-                blk_min_dl.append(min(int(doc_lens[docs].min()), cap))
-    post_data = (np.concatenate(post_parts) if post_parts
-                 else np.zeros(0, dtype=np.uint8))
-    tf_data = (np.concatenate(tf_parts) if tf_parts
-               else np.zeros(0, dtype=np.uint8))
-    num_blocks = len(blk_max)
+    # block geometry: term t's k-th block covers postings [bs, be)
+    nb_t = -(-np.diff(post_offsets) // B)
+    num_blocks = int(nb_t.sum())
+    k = np.arange(num_blocks) - np.repeat(np.cumsum(nb_t) - nb_t, nb_t)
+    bs = np.repeat(post_offsets[:-1], nb_t) + k * B
+    be = np.minimum(bs + B, np.repeat(post_offsets[1:], nb_t))
+    cnt = be - bs
+    docs = postings[:num_postings]
+    tfs = tf[:num_postings]
+    blk_first = docs[bs]
+    blk_max = docs[be - 1]
+    # gap-1 to the previous doc id, 0 at each block's first posting
+    dz = np.zeros(num_postings, dtype=np.int64)
+    np.subtract(docs[1:], docs[:-1], out=dz[1:], dtype=np.int64)
+    dz[1:] -= 1
+    dz[bs] = 0
+    blk_width = _bit_length(np.maximum.reduceat(dz, bs))
+    keep = np.ones(num_postings, dtype=bool)
+    keep[bs] = False
+    post_data = _pack_blocks(dz[keep], cnt - 1, blk_width)
+    del dz, keep
+    mtf = np.maximum.reduceat(tfs, bs)
+    blk_tf_width = _bit_length(mtf - 1)
+    tf_data = _pack_blocks(tfs - 1, cnt, blk_tf_width)
 
     layout, total = _layout_v2(vocab, blob_bytes, num_blocks,
                                len(post_data), len(tf_data), max_doc_id,
@@ -467,14 +491,18 @@ def pack_v2(path, *, term_blob: np.ndarray, term_offsets: np.ndarray,
     put("term_offsets", term_offsets)
     put("term_blob", term_blob)
     put("df", df)
-    put("blk_max", np.asarray(blk_max, dtype=np.int32))
-    put("blk_first", np.asarray(blk_first, dtype=np.int32))
-    put("blk_width", np.asarray(blk_width, dtype=np.uint8))
-    put("blk_tf_width", np.asarray(blk_tf_width, dtype=np.uint8))
+    put("blk_max", blk_max)
+    put("blk_first", blk_first)
+    put("blk_width", blk_width)
+    put("blk_tf_width", blk_tf_width)
     if bits:
+        # saturated integer columns (never floats: the native exporter
+        # must reproduce these bytes exactly)
+        cap = (1 << bits) - 1
         sdt = "<u1" if bits == 8 else "<u2"
-        put("blk_max_tf", np.asarray(blk_max_tf, dtype=sdt))
-        put("blk_min_dl", np.asarray(blk_min_dl, dtype=sdt))
+        put("blk_max_tf", np.minimum(mtf, cap).astype(sdt))
+        min_dl = np.minimum.reduceat(doc_lens[docs], bs)
+        put("blk_min_dl", np.minimum(min_dl, cap).astype(sdt))
     put("post_data", post_data)
     put("tf_data", tf_data)
     put("doc_lens", doc_lens)

@@ -358,3 +358,53 @@ def test_bm25_device_matches_host(both_built):
                     assert ds == pytest.approx(hs, rel=1e-4), q
         finally:
             dev.close()
+
+
+# -- whole-array packer round trip --------------------------------------
+
+
+def test_vectorized_pack_round_trip_at_wiki_shape(tmp_path):
+    """An artifact from the whole-array ``pack`` at a 200,000-doc shape
+    answers postings, AND, top-k by df and BM25 top-k on the host Engine
+    and the DeviceEngine exactly as the loop oracle's artifact does."""
+    from pack_oracle import lex_arrays, oracle_pack_v2, word, zipf_runs
+
+    from parallel_computation_of_an_inverted_index_using_map_reduce_tpu.serve.artifact import (  # noqa: E501
+        pack,
+    )
+
+    runs = zipf_runs(21, vocab=1_500, ndocs=200_000, top_df=8_000, a=0.9)
+    args = lex_arrays(runs, max_doc_id=200_000)
+    new, old = tmp_path / "new.mri", tmp_path / "oracle.mri"
+    pack(new, fmt=3, **args)
+    oracle_pack_v2(old, fmt=3, **args)
+    stride = 26 ** 5 // len(runs) - 1
+    by_df = np.argsort(-args["df"], kind="stable")
+    terms = [word(int(k) * stride).decode() for k in
+             (*by_df[:4], by_df[len(runs) // 2], by_df[-1])]
+    pairs = [terms[:2], [terms[0], terms[4]], [terms[2], terms[5]]]
+    with Engine(new) as host, Engine(old) as ref:
+        dev = DeviceEngine(new)
+        try:
+            for eng in (host, dev):
+                got = eng.postings(eng.encode_batch(terms))
+                want = ref.postings(ref.encode_batch(terms))
+                for g, w, t in zip(got, want, terms):
+                    assert g.tolist() == w.tolist(), t
+                for pair in pairs:
+                    assert eng.query_and(eng.encode_batch(pair)).tolist() \
+                        == ref.query_and(ref.encode_batch(pair)).tolist()
+                for letter in "aemz":
+                    assert eng.top_k(letter, k=5) == ref.top_k(letter, k=5)
+                for q in (terms[:3], pairs[2]):
+                    g = eng.top_k_scored(eng.encode_batch(q), k=10)
+                    w = ref.top_k_scored(ref.encode_batch(q), k=10)
+                    assert [d for d, _ in g] == [d for d, _ in w], q
+                    for (_, gs), (_, ws) in zip(g, w):
+                        assert gs == pytest.approx(ws, rel=1e-4), q
+            # the runs went in as drawn, so the oracle itself is right too
+            for t, k in zip(terms[:2], by_df[:2]):
+                post, = ref.postings(ref.encode_batch([t]))
+                assert post.tolist() == runs[k].tolist()
+        finally:
+            dev.close()
