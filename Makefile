@@ -6,18 +6,16 @@
 
 PY ?= python
 
-.PHONY: build lint test test-fast test-lint test-faults test-parallel test-spill test-chaos test-chaos-all test-wal test-qos test-serve test-serve-device test-daemon test-obs test-segments test-attrib test-cluster test-native-asan test-native-ubsan bench bench-scale bench-sweep bench-build-ooc bench-serve bench-serve-device bench-serve-v2 bench-serve-ranked bench-serve-native bench-daemon bench-scrape bench-segments bench-wal bench-slo bench-cluster bench-brownout bench-qos bench-history clean clean-native
+.PHONY: build lint test test-fast test-lint test-faults test-parallel test-spill test-chaos test-chaos-all test-wal test-qos test-serve test-serve-device test-daemon test-obs test-segments test-attrib test-cluster test-native-asan test-native-ubsan clean clean-native
 
 build:
 	$(PY) -c "from parallel_computation_of_an_inverted_index_using_map_reduce_tpu import native; \
 	          assert native.available(), 'native build failed'; print('native runtime built')"
 
 # repo-contract static analysis (tools/mrilint): exit 0 means clean
-# against the checked-in shrink-only baseline; the bench-history check
-# keeps the README "Bench trajectory" table in sync with BENCH_*.json
+# against the checked-in shrink-only baseline
 lint:
 	$(PY) -m tools.mrilint
-	$(PY) tools/bench_history.py --check
 
 test:
 	$(PY) -m pytest tests/ -q
@@ -153,115 +151,6 @@ test-cluster:
 # are `slow`, so the default `make test-fast` sweep runs them too
 test-attrib: lint
 	$(PY) -m pytest tests/ -q -m attrib
-
-bench:
-	$(PY) bench.py
-
-# 1M-doc streaming benchmark (BASELINE config 4); see bench.py for the
-# MRI_TPU_SCALE_* knobs (REALTEXT=1 switches to the config-5 regime)
-bench-scale:
-	$(PY) bench.py --scale
-
-# host map-phase scaling curve: cpu e2e at 1/2/4 scan workers on the
-# same corpus, with the per-worker stage split (prints a JSON line)
-bench-sweep:
-	$(PY) bench.py --sweep
-
-# out-of-core build bench: spill-tier wall vs the in-memory parallel
-# build on a >= 20x-budget Zipf corpus, byte-parity + peak-memory
-# gated -> BENCH_BUILD_OOC_r15.json
-bench-build-ooc:
-	$(PY) tools/bench_build_ooc.py
-
-# query-serving QPS/latency bench against the packed artifact (Zipf
-# workload, batch sizes 1/32/1024; prints a JSON line) — see
-# tools/bench_serve.py for the MRI_SERVE_* knobs
-bench-serve:
-	$(PY) tools/bench_serve.py
-
-# host-vs-device serving A/B (batch 1/1K/8K/64K, per-op breakdown,
-# byte-parity + zero-recompile assertions) -> BENCH_SERVE_DEVICE_r06.json
-bench-serve-device:
-	$(PY) tools/bench_serve.py --device-ab
-
-# artifact format v1-vs-v2 A/B (bytes on disk, boolean QPS, cold-decode
-# latency, BM25 throughput; byte-parity gated) -> BENCH_SERVE_V2_r09.json
-bench-serve-v2:
-	$(PY) tools/bench_serve.py --format-ab
-
-# ranked-query A/B on a v2.1 artifact: exhaustive vs Block-Max WAND vs
-# MaxScore at k=1/10/100 over the Zipf mix, byte-parity gated, with
-# cold-sweep block-skip ratios -> BENCH_RANKED_r11.json
-bench-serve-ranked:
-	$(PY) tools/bench_serve.py --ranked-ab
-
-# native serve-kernel A/B: numpy host engine vs the C++ mri_serve_*
-# kernels on the same v2.1 artifact (bm25 top-10 QPS at batch
-# 1/8/32/1024 + AND QPS, byte-parity gated against the numpy oracle,
-# >= 3x the r11 ranked gate) -> BENCH_NATIVE_r16.json
-bench-serve-native:
-	$(PY) tools/bench_serve.py --native-ab
-
-# resident-daemon bench: coalesced pipelined capacity vs the batch-1
-# closed-loop baseline, plus an open-loop (Poisson) sweep reporting
-# p50/p99 from scheduled arrival, shed rate, and deadline-miss rate at
-# 3 offered loads -> BENCH_DAEMON_r07.json
-bench-daemon:
-	$(PY) tools/bench_serve.py --daemon-bench
-
-# observability overhead gate: Prometheus-vs-stats counter parity on a
-# live daemon + the `metrics` op priced against the r09 serving
-# capacity (1 Hz scrape must cost <1%) -> BENCH_SCRAPE_r10.json
-bench-scrape:
-	$(PY) tools/bench_serve.py --scrape-check
-
-# incremental-indexing A/B: append->visible refresh latency, query QPS
-# at 1/4/16 segments vs the single-artifact baseline (byte-parity
-# gated), and compaction cost -> BENCH_SEGMENTS_r12.json
-bench-segments:
-	$(PY) tools/bench_serve.py --segments-ab
-
-# durability A/B: the same live-daemon mutation schedule with the WAL
-# off vs on (ack p99 gated at 2x, byte-parity between the legs), plus
-# cold replica catch-up rate by segment shipping -> BENCH_WAL_r17.json
-bench-wal:
-	$(PY) tools/bench_serve.py --wal-ab
-
-# operational-health overhead gate: rolling-windows sampler tick + a
-# 1 Hz `slo` poll priced in-run (<1% of a serving second), with `mri
-# top --once --json` parity vs the raw ops -> BENCH_SLO_r14.json
-bench-slo:
-	$(PY) tools/bench_serve.py --slo-check
-
-# doc-sharded cluster A/B: monolithic engine vs D local shard daemons
-# behind the scatter-gather router at D=4/8 (pipelined + open-loop
-# Poisson ranked load, byte-parity gated vs the monolithic artifact,
-# hedged-vs-unhedged p99 under an injected slow shard)
-# -> BENCH_CLUSTER_r18.json; see tools/bench_serve.py for the
-# MRI_CLUSTER_BENCH_* knobs
-bench-cluster:
-	$(PY) tools/bench_serve.py --cluster-ab
-
-# brownout A/B: retry amplification on a D=2 cluster under a shard
-# blackout and an intermittent overload storm (default token-bucket
-# budget vs a loose contrast leg, gated at 1.1x), plus CoDel adaptive
-# admission vs a fixed queue at 2x measured capacity (compliant p99
-# gated at 2x unloaded) -> BENCH_BROWNOUT_r19.json
-bench-brownout:
-	$(PY) tools/bench_serve.py --brownout-ab
-
-# result-cache + QoS A/B: cached-hot vs uncached Zipf replay on one
-# daemon (speedup gated at 5x, byte-parity gated), then a diurnal-burst
-# tank tenant vs a paying tenant at 2x measured capacity — paying p99
-# gated at 1.2x its alone run, with an unfenced contrast leg
-# -> BENCH_QOS_r20.json
-bench-qos:
-	$(PY) tools/bench_serve.py --qos-ab
-
-# print the cross-round BENCH_*.json trajectory table (ratios against
-# each round's own baseline); `--write` regenerates the README block
-bench-history:
-	$(PY) tools/bench_history.py
 
 # drop every hashed native build artifact — production AND sanitizer
 # variants, in both the in-tree dir and the /tmp fallback (stale .so

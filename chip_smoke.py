@@ -48,7 +48,7 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent
 PKG = "parallel_computation_of_an_inverted_index_using_map_reduce_tpu"
 
-#: the test_in-shaped corpus bench.py measures (about 5.8 MB)
+#: a test_in-shaped corpus (about 5.8 MB)
 BUILD_CORPUS = dict(num_docs=355, vocab_size=33_000, tokens_per_doc=2900,
                     seed=7)
 #: BASELINE.json config 4

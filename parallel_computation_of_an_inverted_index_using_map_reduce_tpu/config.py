@@ -162,8 +162,8 @@ class IndexConfig:
     # ``index.manifest.json`` output manifest (per-letter-file md5)
     # written after every emit — ``--verify`` re-checks it later.
     # Recovery bugs surface as AuditError (exit 2), never as silently
-    # wrong bytes.  Cheap (<5% of cpu_ms; bench tracks ``audit_ms``),
-    # but off by default to keep the measured hot path exact.
+    # wrong bytes.  Cheap (the run report tracks ``audit_ms``), but
+    # off by default to keep the measured hot path exact.
     audit: bool = False
     # Emit-side ownership for the multi-chip pipelined path:
     #   "merged" — one host assembles and writes all 26 files (default)

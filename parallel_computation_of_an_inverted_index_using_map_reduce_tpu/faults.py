@@ -1144,7 +1144,7 @@ class DegradationReport:
             return [s["doc_id"] for s in self.skips]
 
     def summary(self) -> dict:
-        """The stats-dict form (bench JSON / ``--stats`` fields)."""
+        """The stats-dict form (``--stats`` fields)."""
         with self._lock:
             return {
                 "read_retries": self.read_retries,

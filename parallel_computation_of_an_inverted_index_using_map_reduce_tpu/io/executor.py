@@ -59,7 +59,7 @@ class PipelinedWindowReader:
 
     ``read_wait_s`` / ``consume_wait_s`` accumulate the time the reader
     sat blocked on a free arena and the consumer sat blocked on a filled
-    one — the pipeline-bubble split the bench stage report uses.  Each
+    one — the pipeline-bubble split of the run's stage report.  Each
     counter is written by exactly one thread (reader / consumer), so
     per-reader instances are race-free and the multi-worker path merges
     them by plain summation.

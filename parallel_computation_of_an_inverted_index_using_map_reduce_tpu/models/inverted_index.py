@@ -90,7 +90,7 @@ class InvertedIndexModel:
         # One degradation report per run: every read path below records
         # retries and skipped documents into it, and the summary rides
         # the stats dict into the CLI (exit faults.EXIT_DEGRADED when
-        # documents were skipped) and the bench JSON.
+        # documents were skipped).
         report = faults.begin_run()
         # Chrome trace_event export (--trace-out): one collector per
         # run; the host pipeline records per-stage spans into it and
@@ -245,8 +245,7 @@ class InvertedIndexModel:
 
         Stage attribution lands in the ``stage_*_ms`` counters: read is
         the reader thread's busy time, tokenize the native scan +
-        postings finalize, emit the letter-file render + write — the
-        split bench.py reports as ``host_stage_split``.
+        postings finalize, emit the letter-file render + write.
         """
         from .. import native
         from ..io.executor import PipelinedWindowReader

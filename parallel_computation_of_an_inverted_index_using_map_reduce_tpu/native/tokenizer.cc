@@ -1879,9 +1879,8 @@ void PartialFlatten(HostStreamState& h) {
 // keys — O(V) per pass, 8 passes, no comparator branches; terms sharing
 // a full 8-byte prefix land adjacent and their (rare) groups get a tiny
 // comparison sort over the padded tails afterwards.  Shared by the v1
-// and v2 artifact exporters (on the 1-core bench container this is ~3x
-// faster than the comparison sort in SortedOrder, which the pack-time
-// budget cannot afford).
+// and v2 artifact exporters (it replaces the comparison sort in
+// SortedOrder at pack time).
 std::vector<int32_t> LexOrderRadix(const StreamState& st, int32_t V) {
   const uint8_t* arena = st.arena.data();
   std::vector<std::pair<uint64_t, int32_t>> part(std::max(V, 1));
@@ -2529,9 +2528,7 @@ int32_t mri_hidxm_export(void* mh, uint8_t* vocab_packed, int32_t* word_lens,
 // the caller's file buffer at the offsets the Python layout computed —
 // compact term blob (no fixed-width round-trip), i32 df, and postings
 // DELTA-ENCODED in place right after each term's run merge — so the
-// Python side is left with just checksum + header + one write().  On
-// the 1-core bench container this is the difference between the pack
-// fitting the <=10 %-of-e2e budget and tripling it.
+// Python side is left with just checksum + header + one write().
 int32_t mri_hidxm_export_payload(void* mh, uint8_t* base,
                                  int64_t off_letter_dir,
                                  int64_t off_term_offsets,

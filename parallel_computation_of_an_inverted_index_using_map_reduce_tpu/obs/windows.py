@@ -54,8 +54,8 @@ class RollingWindows:
 
     ``counters`` / ``histograms`` name the registry series to track;
     they are get-or-created up front so the sampler never races metric
-    creation.  :meth:`sample` is public so tests (and the pricing
-    bench) can tick it deterministically without the thread.
+    creation.  :meth:`sample` is public so tests can tick it
+    deterministically without the thread.
     """
 
     def __init__(self, registry: obs_metrics.Registry,

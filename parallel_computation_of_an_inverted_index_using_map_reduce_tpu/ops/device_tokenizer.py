@@ -46,7 +46,7 @@ its corpus maxes at 38, so ``width=48`` covers real text with margin.
 This is the TPU-first endpoint of the design space: on hardware where
 the host<->device link is ~free (local PCIe), the whole pipeline runs
 at device sort throughput; on a high-RTT link the host-scan engines
-win end-to-end (bench.py records both, labeled).  Reference seams
+win end-to-end.  Reference seams
 re-expressed: mapper tokenize+emit (main.c:85-124) and reducer
 dedup/sort (main.c:126-242) become one fused program with no
 intermediate materialization at all — not even the (term, doc) pair

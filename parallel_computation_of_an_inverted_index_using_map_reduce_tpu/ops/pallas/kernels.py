@@ -22,9 +22,9 @@ Two kernels, both exact drop-ins for their XLA counterparts:
 Both run compiled on TPU and in interpreter mode elsewhere (tests force
 ``interpret=True`` on the CPU backend via :func:`_should_interpret`).
 
-Measured on a real v5e (bench.py ``kernel_timings``, 2^20 keys,
-device-side dispatch loops): the fused kernel runs ~18 us vs ~14 us for
-the XLA three-kernel path — XLA's own fusion already wins here, and the
+Measured on a v5e in round 3 (2^20 keys, device-side dispatch loops;
+not in the ledger): the fused kernel ran ~18 us vs ~14 us for the XLA
+three-kernel path — XLA's own fusion already wins here, and the
 sequential-grid carry serializes what XLA parallelizes.  The kernel is
 kept (a) as the measured datapoint behind that conclusion and (b) as
 the fused-sweep pattern the playbook needs at sizes where the extra

@@ -22,8 +22,7 @@ damage — adapted to an append-only record stream::
              8s    adler32 hex of the payload (utils.checksum spelling)
 
 Unlike spill files the WAL **fsyncs every append**: its whole point is
-surviving SIGKILL, so durability is the product, not overhead (the
-``--wal-ab`` bench prices it).
+surviving SIGKILL, so durability is the product, not overhead.
 
 Sequencing model: every record carries a monotonic ``seq``; every
 manifest publish stamps ``wal_seq`` with the seq it covers.  Replay
@@ -84,7 +83,7 @@ def corrupt_path(root) -> Path:
 
 def wal_enabled() -> bool:
     """``MRI_SEGMENT_WAL`` (default on).  Off restores the pre-WAL
-    publish-only durability — the A/B the bench prices."""
+    publish-only durability."""
     return bool(envknobs.get(WAL_ENV))
 
 

@@ -8,7 +8,7 @@ it — host numpy over mmap views (:mod:`~.engine`) and device-resident
 jit/shard_map (:mod:`~.device_engine`, selected via
 :func:`create_engine`) — and the LRU hot-term cache the host engine
 decodes postings through (:mod:`~.cache`).  ``mri-tpu query`` (cli.py)
-and ``tools/bench_serve.py`` sit on top, and :mod:`~.daemon` keeps one
+sits on top, and :mod:`~.daemon` keeps one
 engine resident behind a JSON-lines protocol (``mri-tpu serve``) with
 micro-batch coalescing, admission control, deadlines, graceful drain,
 and crash-safe hot reload.

@@ -1,15 +1,15 @@
 """Resident serving daemon over one loaded ``index.mri`` artifact.
 
 ``mri query`` pays the artifact open + engine warmup on every
-invocation, which caps it near the batch-1 closed-loop floor (~27K
-lookups/s) no matter how fast the engine is at batch 1024 (653K/s,
-BENCH_SERVE_r05.json).  :class:`ServeDaemon` closes that gap: load
-once, accept concurrent connections speaking a JSON-lines protocol,
-and coalesce whatever is pending into micro-batches for the existing
-vectorized batch path — the multi-round batching discipline of
-"Sorting, Searching, and Simulation in the MapReduce Framework"
-applied to read traffic, with the engine kept as the stateless core
-(DrJAX's split between algorithm and orchestration).
+invocation, which caps it near the batch-1 closed-loop floor no matter
+how fast the engine is at large batches.  :class:`ServeDaemon` closes
+that gap: load once, accept concurrent connections speaking a
+JSON-lines protocol, and coalesce whatever is pending into
+micro-batches for the existing vectorized batch path — the
+multi-round batching discipline of "Sorting, Searching, and
+Simulation in the MapReduce Framework" applied to read traffic, with
+the engine kept as the stateless core (DrJAX's split between
+algorithm and orchestration).
 
 The headline is the robustness envelope, not raw QPS:
 

@@ -966,8 +966,8 @@ class DeviceEngine:
         return self._ops.stats()
 
     def compile_stats(self) -> dict:
-        """Jit-cache census: the bench's zero-recompile assertion
-        compares this before/after the steady-state run."""
+        """Jit-cache census: the zero-recompile test compares this
+        before/after the steady-state run."""
         fns = ([self._lookup_fn] + list(self._decode_fns.values())
                + list(self._bool_fns.values())
                + list(self._topk_fns.values())

@@ -1,8 +1,8 @@
 """Process-level JAX setup shared by every entry point that runs on a device.
 
 :func:`setup` places JAX's persistent compilation cache and starts
-counting backend compile seconds; ``cli.main``, ``bench.py``'s child and
-the benchmark's drivers call it before their first compile.  The cache
+counting backend compile seconds; ``cli.main``, ``chip_smoke.py``'s
+phases and the benchmark's drivers call it before their first compile.  The cache
 goes where ``JAX_COMPILATION_CACHE_DIR`` says when that is set (JAX reads
 the variable itself, and nothing else is set here); otherwise it goes to
 ``<checkout>/.jax_cache``, a fixed path, because the cache key includes

@@ -1,11 +1,11 @@
 """Single declaration point for every ``MRI_*`` environment knob.
 
-Every knob the package, the benches, and the tools read from the
-environment is declared here once — name, type, default, bounds, and a
-help line — and read through :func:`get`.  Invalid values raise a
-one-line :class:`KnobError` (a ``ValueError``) naming the variable, so
-every CLI surface maps it to exit 2 instead of surfacing a bare
-``int()`` traceback three layers down a worker thread.
+Every knob the package and its tools read from the environment is
+declared here once — name, type, default, bounds, and a help line —
+and read through :func:`get`.  Invalid values raise a one-line
+:class:`KnobError` (a ``ValueError``) naming the variable, so every
+CLI surface maps it to exit 2 instead of surfacing a bare ``int()``
+traceback three layers down a worker thread.
 
 The ``mrilint`` env-knobs checker rejects raw ``os.environ["MRI_*"]``
 reads anywhere else, and the readme-knobs checker keeps the README
@@ -102,7 +102,6 @@ _SCOPE_TITLES = {
     "faults": "Fault injection & retries",
     "serve": "Query serving",
     "obs": "Observability",
-    "bench": "Benchmarks",
     "test": "Test hooks",
 }
 
@@ -139,7 +138,7 @@ def markdown_table() -> str:
 
 
 # ---------------------------------------------------------------------
-# Declarations.  Scopes: build (index pipeline), faults, serve, bench,
+# Declarations.  Scopes: build (index pipeline), faults, serve, obs,
 # test (crash-injection hooks the e2e suite arms in subprocesses).
 # ---------------------------------------------------------------------
 
@@ -452,94 +451,6 @@ declare("MRI_OBS_LOG_RATE_LIMIT", int, 200,
         "excess records are dropped and counted in "
         "mri_obs_log_dropped_total. 0 disables the limiter.",
         scope="obs", minimum=0)
-
-# -- benchmarks -------------------------------------------------------
-declare("MRI_TPU_BENCH_CORPUS", str, None,
-        "Corpus directory override for the e2e bench legs.",
-        scope="bench")
-declare("MRI_TPU_SCALE_DOCS", int, 1_000_000,
-        "Synthetic corpus size for the scale bench.", scope="bench")
-declare("MRI_TPU_SCALE_VOCAB", int, 100_000,
-        "Synthetic vocabulary size for the scale bench.", scope="bench")
-declare("MRI_TPU_SCALE_SHARDS", int, 0,
-        "Scale-bench shard count (0: all devices).", scope="bench")
-declare("MRI_TPU_SCALE_DEVTOK", int, 0,
-        "1: scale bench runs the device-tokenizer streaming path.",
-        scope="bench")
-declare("MRI_TPU_SCALE_REALTEXT", int, 0,
-        "1: synthesize Zipf-ish real-looking text instead of uniform "
-        "tokens.", scope="bench")
-declare("MRI_TPU_SCALE_SALT", int, 1,
-        "1: salt the synthetic corpus per repeat (defeats caching).",
-        scope="bench")
-declare("MRI_TPU_SCALE_REPEATS", int, 8,
-        "Timed repeats per scale-bench configuration.", scope="bench")
-declare("MRI_TPU_SCALE_CHUNK", int, 100_000,
-        "Docs per streamed chunk in the scale bench.", scope="bench")
-declare("MRI_TPU_SCALE_CKPT", str, None,
-        "Checkpoint directory for the devtok scale leg (unset: no "
-        "checkpointing).", scope="bench")
-declare("MRI_TPU_SCALE_CKPT_EVERY", int, 2,
-        "Checkpoint cadence in chunks for the devtok scale leg.",
-        scope="bench")
-declare("MRI_TPU_SCALE_SKEW", str, None,
-        "Truthy: report per-letter skew for the realtext corpus.",
-        scope="bench")
-declare("MRI_TPU_SCALE_CROSSCHECK", str, None,
-        "Truthy: cross-check scale-bench output against the oracle.",
-        scope="bench")
-declare("MRI_BENCH_SWEEP_WORKERS", str, "1,2,4",
-        "Comma list of worker counts for the host sweep.",
-        scope="bench")
-declare("MRI_SERVE_BATCHES", str, "1,32,1024",
-        "Comma list of batch sizes for the serve bench.",
-        scope="bench")
-declare("MRI_SERVE_AB_BATCHES", str, "1,1024,8192,65536",
-        "Comma list of batch sizes for the host/device A/B leg.",
-        scope="bench")
-declare("MRI_SERVE_LOOKUPS", int, 200_000,
-        "Total single-term lookups per serve-bench batch size.",
-        scope="bench")
-declare("MRI_SERVE_AB_MAX_BATCHES", int, 256,
-        "Per-batch-size cap on timed batches in A/B mode.",
-        scope="bench")
-declare("MRI_SERVE_ZIPF_S", float, 1.1,
-        "Zipf exponent of the serve-bench term-popularity draw.",
-        scope="bench")
-declare("MRI_SERVE_SEED", int, 17,
-        "RNG seed for serve-bench workloads.", scope="bench")
-declare("MRI_SERVE_OPEN_SECONDS", float, 3.0,
-        "Per-leg duration of the open-loop serve bench.",
-        scope="bench")
-declare("MRI_DAEMON_PIPELINE_N", int, 60_000,
-        "Requests in the daemon pipelined capacity probe.",
-        scope="bench")
-declare("MRI_DAEMON_CLOSED_N", int, 3_000,
-        "RPCs in the daemon closed-loop latency leg.", scope="bench")
-declare("MRI_DAEMON_OPEN_SECONDS", float, 2.0,
-        "Per-leg duration of the daemon open-loop bench.",
-        scope="bench")
-declare("MRI_DAEMON_DEADLINE_MS", float, 25.0,
-        "deadline_ms carried by every open-loop bench request.",
-        scope="bench")
-declare("MRI_DAEMON_LOAD_FACTORS", str, "0.4,0.8,1.6",
-        "Comma list of offered-load multipliers over measured "
-        "capacity.", scope="bench")
-declare("MRI_DAEMON_WINDOW", int, 512,
-        "In-flight window of the daemon pipelined probe.",
-        scope="bench")
-declare("MRI_DAEMON_OPEN_WINDOW", int, 2400,
-        "Max in-flight requests in the daemon open-loop bench.",
-        scope="bench")
-declare("MRI_CLUSTER_BENCH_N", int, 12000,
-        "Ranked requests per cluster-bench throughput leg "
-        "(--cluster-ab).", scope="bench")
-declare("MRI_CLUSTER_BENCH_SHARDS", str, "4,8",
-        "Comma list of shard counts the cluster bench sweeps.",
-        scope="bench")
-declare("MRI_CLUSTER_BENCH_SLOW_MS", float, 20.0,
-        "Injected shard-slow delay in ms for the cluster bench's "
-        "hedged-vs-unhedged p99 comparison.", scope="bench")
 
 # -- test hooks -------------------------------------------------------
 declare("MRI_EMIT_KILL_AFTER_LETTERS", int, None,

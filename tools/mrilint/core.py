@@ -24,7 +24,7 @@ from pathlib import Path
 REPO_ROOT = Path(__file__).resolve().parents[2]
 PACKAGE = "parallel_computation_of_an_inverted_index_using_map_reduce_tpu"
 #: default lint scope (tests are exercised by pytest, not contract-bound)
-DEFAULT_TARGETS = (PACKAGE, "tools", "bench.py", "mri_tpu.py")
+DEFAULT_TARGETS = (PACKAGE, "tools", "mri_tpu.py")
 _EXCLUDE_PARTS = {"__pycache__", "_build", ".git"}
 BASELINE_PATH = Path(__file__).resolve().parent / "baseline.txt"
 
